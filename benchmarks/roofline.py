@@ -18,9 +18,9 @@ Per (arch x shape x mesh) cell, from artifacts/dryrun/<cell>.json:
     collective term = collective_bytes_per_device / link_bw      [s]
 
 (cost_analysis of the SPMD-partitioned executable is already per-device, so
-the prompt's "/ chips" is folded in.) Hardware: TPU v5e-like — 197 TFLOP/s
-bf16, 819 GB/s HBM, ~50 GB/s/link ICI (3D-torus links; we charge the
-busiest single link, a conservative serialization bound).
+the prompt's "/ chips" is folded in.) Hardware: the peaks of
+:data:`TARGET_KIND` from :data:`PEAKS` (we charge the busiest single ICI
+link, a conservative serialization bound).
 
 Also reported: MODEL_FLOPS (6ND train / 2ND forward, N_active for MoE), the
 useful-compute ratio MODEL_FLOPS / HLO_FLOPs (catches remat & masked-block
@@ -37,12 +37,35 @@ from typing import Dict, List, Optional
 
 from repro.configs import SHAPES, get_config
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s
-LINK_BW = 50e9  # B/s per ICI link
-#: fixed dispatch cost charged per pallas_call launch (host->device setup,
-#: grid program bring-up) — the term the megakernel amortizes: a chunked
-#: horizon pays it steps/every times, the megakernel exactly once.
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+PEAKS = {
+    "TPU v5 lite": dict(
+        flops_bf16=197e12,  # FLOP/s
+        hbm_bytes_per_s=819e9,
+        # 1,600 Gbit/s chip-to-chip interconnect over 4 ICI links
+        ici_link_bytes_per_s=1600e9 / 8 / 4,
+        source="Google Cloud documentation, 'TPU v5e' (system architecture)",
+    ),
+}
+#: the chip this analytic model describes (TPU v5e)
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(kind: str) -> Dict:
+    """The peak table row for a device kind; an unknown kind is an error,
+    never a default."""
+    if kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[kind]
+
+
+PEAK_FLOPS = peaks(TARGET_KIND)["flops_bf16"]
+HBM_BW = peaks(TARGET_KIND)["hbm_bytes_per_s"]
+LINK_BW = peaks(TARGET_KIND)["ici_link_bytes_per_s"]
+#: ASSUMED, not measured: fixed dispatch cost charged per pallas_call launch
+#: (host->device setup, grid program bring-up) — the term the megakernel
+#: amortizes: a chunked horizon pays it steps/every times, the megakernel
+#: exactly once. No chip trace has measured it yet.
 LAUNCH_OVERHEAD_US = 4.0
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
@@ -207,7 +230,7 @@ def pde_launch_rows(steps: int = 240):
                         f";bytes_per_step={nbytes}"
                         f";t_mem_us={t_mem_us:.4f};t_launch_us={t_launch_us:.4f}"
                         f";bound={'launch' if t_launch_us > t_mem_us else 'bandwidth'}"
-                        f";launch_overhead_us={LAUNCH_OVERHEAD_US}",
+                        f";launch_overhead_us_assumed={LAUNCH_OVERHEAD_US}",
                     )
                 )
     return rows

@@ -228,6 +228,9 @@ def main() -> None:
         help="us_per_call warn threshold as a multiple of the baseline",
     )
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     os.makedirs(args.json_dir, exist_ok=True)
 

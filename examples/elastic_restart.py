@@ -2,18 +2,24 @@
 on a DIFFERENT device count from the latest atomic checkpoint.
 
     PYTHONPATH=src python examples/elastic_restart.py
+
+Each phase is a child process on virtual CPU devices (``JAX_PLATFORMS=cpu``):
+this demo never touches an accelerator.
 """
 
 import os
+import shutil
 import subprocess
 import sys
+import tempfile
 
-CKPT = "/tmp/repro_elastic_demo"
+CKPT = os.path.join(tempfile.gettempdir(), "repro_elastic_demo")
 
 
 def run(n_devices, steps, extra=()):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     cmd = [
         sys.executable, "-m", "repro.launch.train",
@@ -26,7 +32,7 @@ def run(n_devices, steps, extra=()):
 
 
 def main():
-    subprocess.run(["rm", "-rf", CKPT])
+    shutil.rmtree(CKPT, ignore_errors=True)
     print("=== phase 1: train on 4 devices, inject failure at step 25 ===")
     run(4, 40, ["--inject-failure-at", "25"])
     print("\n=== phase 2: cluster shrank — resume on 2 devices ===")

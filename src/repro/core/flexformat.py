@@ -42,6 +42,7 @@ __all__ = [
     "min_subnormal",
     "exponent_bias",
     "unbiased_exponent",
+    "max_exponent",
     "exponent_redundant",
     "pack_r2f2",
     "unpack_r2f2",
@@ -100,31 +101,43 @@ E5M8 = FlexFormat(5, 8, 0)  # 14-bit fixed
 E8M23 = FlexFormat(8, 23, 0)  # f32 itself (identity quantization)
 
 
+def _is_static(v) -> bool:
+    return isinstance(v, (int, np.integer, np.ndarray))
+
+
+def _int(v):
+    """Integer format parameter: Python/NumPy integers stay NumPy, so formats
+    known at trace time fold into constants; anything else is a jnp int32
+    array (a runtime split). Inside a Pallas TPU kernel this is what keeps
+    static formats from ever reaching the chip as scalar bit casts."""
+    return np.asarray(v, np.int32) if _is_static(v) else jnp.asarray(v, jnp.int32)
+
+
 def exponent_bias(e_bits) -> jnp.ndarray:
-    return (1 << (jnp.asarray(e_bits, jnp.int32) - 1)) - 1
+    return (1 << (_int(e_bits) - 1)) - 1
 
 
 def _emax(e_bits):
     # All-ones biased exponent reserved for inf/nan (IEEE convention; matches
     # the paper's 65504 / 1.84e19 examples).
-    return (1 << (jnp.asarray(e_bits, jnp.int32) - 1)) - 1
+    return (1 << (_int(e_bits) - 1)) - 1
 
 
 def _emin(e_bits):
-    return 2 - (1 << (jnp.asarray(e_bits, jnp.int32) - 1))
+    return 2 - (1 << (_int(e_bits) - 1))
 
 
 def max_normal(e_bits, m_bits) -> jnp.ndarray:
     """Largest finite value of E(e)M(m), as f32."""
-    return _scale_pow2(2.0 - _pow2(-jnp.asarray(m_bits, jnp.int32)), _emax(e_bits))
+    return jnp.asarray(_scale_pow2(2.0 - _pow2(-_int(m_bits)), _emax(e_bits)), jnp.float32)
 
 
 def min_normal(e_bits) -> jnp.ndarray:
-    return _pow2(_emin(e_bits))
+    return jnp.asarray(_pow2(_emin(e_bits)), jnp.float32)
 
 
 def min_subnormal(e_bits, m_bits) -> jnp.ndarray:
-    return _pow2(_emin(e_bits) - jnp.asarray(m_bits, jnp.int32))
+    return jnp.asarray(_pow2(_emin(e_bits) - _int(m_bits)), jnp.float32)
 
 
 def _bits(x):
@@ -140,7 +153,11 @@ def _pow2(n):
 
     (XLA lowers jnp.exp2 to exp(x*ln2) on CPU which is NOT exact for integer
     powers -- exactness here is load-bearing for bit-exact quantization.)
+    A static ``n`` is evaluated in NumPy (a constant); a traced ``n`` keeps
+    its shape, so kernels pass runtime splits as ``(1, 1)`` vectors.
     """
+    if _is_static(n):
+        return np.ldexp(np.float32(1.0), np.clip(n, -149, 127)).astype(np.float32)
     n = jnp.asarray(n, jnp.int32)
     normal = _from_bits((jnp.clip(n, -126, 127) + 127).astype(jnp.uint32) << _F32_MANT_BITS)
     sub_shift = jnp.clip(n + 149, 0, _F32_MANT_BITS).astype(jnp.uint32)
@@ -151,8 +168,8 @@ def _pow2(n):
 def _scale_pow2(x, n):
     """Exact x * 2**n in (up to) two exact power-of-two multiplies, valid for
     |n| <= 254 as long as the final result is representable."""
-    n = jnp.asarray(n, jnp.int32)
-    h1 = jnp.clip(n, -126, 127)
+    n = _int(n)
+    h1 = np.clip(n, -126, 127) if _is_static(n) else jnp.clip(n, -126, 127)
     return x * _pow2(h1) * _pow2(n - h1)
 
 
@@ -160,6 +177,25 @@ def unbiased_exponent(x) -> jnp.ndarray:
     """floor(log2(|x|)) for normal f32 inputs, via bit extraction (int32)."""
     u = _bits(x) & _U32_ABS_MASK
     return (u >> _F32_MANT_BITS).astype(jnp.int32) - _F32_BIAS
+
+
+def max_exponent(x, axis=None) -> jnp.ndarray:
+    """Unbiased exponent of the largest finite magnitude in ``x``, reduced
+    over ``axis`` (default: all axes) with the reduced axes kept. Zeros,
+    f32 subnormals and non-finite values count as exponent -127, so the
+    result is the same whether or not the device flushes subnormals.
+
+    The reduced axes stay as size-1 axes so that a kernel's per-block
+    result remains a vector: a Pallas TPU kernel can bit-cast vectors but
+    not scalars. A whole-array reduction of rank > 2 folds the leading axes
+    first, then the trailing two, which is the order the TPU kernel
+    compiler can lay out."""
+    mag = jnp.where(jnp.isfinite(x), jnp.abs(jnp.asarray(x, jnp.float32)), 0.0)
+    if axis is None and mag.ndim > 2:
+        lead = jnp.max(mag, axis=tuple(range(mag.ndim - 2)))
+        top = jnp.max(lead, keepdims=True).reshape((1,) * mag.ndim)
+        return unbiased_exponent(top)
+    return unbiased_exponent(jnp.max(mag, axis=axis, keepdims=True))
 
 
 def _round_mantissa_rne(u_abs, m_bits):
@@ -198,8 +234,8 @@ def quantize_em_with_flags(x, e_bits, m_bits, tail_trunc_bits=None):
     whose own subnormals are f32-normal (all the paper's <=16-bit formats).
     """
     x = jnp.asarray(x, jnp.float32)
-    e_bits = jnp.asarray(e_bits, jnp.int32)
-    m_bits = jnp.asarray(m_bits, jnp.int32)
+    e_bits = _int(e_bits)
+    m_bits = _int(m_bits)
 
     u = _bits(x)
     sign = u & _U32_SIGN_MASK
@@ -299,8 +335,8 @@ def pack_r2f2(x, fmt: FlexFormat, k):
     """Encode quantized f32 values into the ``total_bits``-wide integer
     payload for format ``fmt`` at flex split ``k``. Assumes ``x`` is already
     representable (i.e. output of quantize_em for the same (e, m))."""
-    e_bits = fmt.eb + jnp.asarray(k, jnp.int32)
-    m_bits = fmt.mb + fmt.fx - jnp.asarray(k, jnp.int32)
+    e_bits = fmt.eb + _int(k)
+    m_bits = fmt.mb + fmt.fx - _int(k)
     x = jnp.asarray(x, jnp.float32)
     u = _bits(x)
     sign = (u >> 31).astype(jnp.uint32)
@@ -320,7 +356,9 @@ def pack_r2f2(x, fmt: FlexFormat, k):
     mant_norm = (mant32 >> mshift).astype(jnp.uint32)
     # subnormal: value = 0.mant * 2**emin -> mantissa field = round(|x| / 2**(emin-m))
     sub_field = jnp.round(_scale_pow2(jnp.abs(x), -(emin - m_bits)))
-    mant_sub = sub_field.astype(jnp.uint32)
+    # via int32: the TPU kernel compiler converts float <-> signed only
+    # (the field is below 2**23 either way)
+    mant_sub = sub_field.astype(jnp.int32).astype(jnp.uint32)
 
     exp_field = jnp.where(is_sub | is_zero, 0, unb + bias).astype(jnp.uint32)
     exp_field = jnp.where(is_inf | is_nan, ((1 << e_bits) - 1).astype(jnp.uint32), exp_field)
@@ -337,14 +375,14 @@ def pack_r2f2(x, fmt: FlexFormat, k):
 
 def unpack_r2f2(payload, fmt: FlexFormat, k):
     """Decode :func:`pack_r2f2` payloads back to f32."""
-    e_bits = fmt.eb + jnp.asarray(k, jnp.int32)
-    m_bits = fmt.mb + fmt.fx - jnp.asarray(k, jnp.int32)
+    e_bits = fmt.eb + _int(k)
+    m_bits = fmt.mb + fmt.fx - _int(k)
     payload = jnp.asarray(payload, jnp.uint32)
 
     one = jnp.uint32(1)
     m_mask = (one << m_bits.astype(jnp.uint32)) - one
     e_mask = (one << e_bits.astype(jnp.uint32)) - one
-    mant = (payload & m_mask).astype(jnp.float32)
+    mant = (payload & m_mask).astype(jnp.int32).astype(jnp.float32)
     expf = ((payload >> m_bits.astype(jnp.uint32)) & e_mask).astype(jnp.int32)
     sign = (payload >> (e_bits + m_bits).astype(jnp.uint32)) & one
 

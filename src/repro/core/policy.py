@@ -27,7 +27,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .flexformat import FlexFormat, unbiased_exponent
+from .flexformat import FlexFormat, max_exponent
 from .r2f2 import (  # noqa: F401
     _needed_e_bits,
     _needed_e_bits_lo,
@@ -162,8 +162,7 @@ def tracker_init(n_sites: int, fmt: FlexFormat, k0=None) -> RangeTracker:
 
 
 def _site_max_exp(x) -> jnp.ndarray:
-    mag = jnp.where(jnp.isfinite(x), jnp.abs(x), 0.0)
-    return unbiased_exponent(jnp.maximum(jnp.max(mag), jnp.float32(1e-38))).astype(jnp.float32)
+    return max_exponent(x).reshape(()).astype(jnp.float32)
 
 
 def _k_for(hi, lo, fmt: FlexFormat):

@@ -35,10 +35,8 @@ import jax.numpy as jnp
 from .flexformat import (
     FlexFormat,
     exponent_redundant,
-    max_normal,
-    min_normal,
+    max_exponent,
     quantize_em_with_flags,
-    unbiased_exponent,
 )
 
 __all__ = [
@@ -87,23 +85,30 @@ def product_guard_bits(fmt: FlexFormat, k) -> jnp.ndarray:
     return fmt.mb + 1 + jnp.asarray(k, jnp.int32)
 
 
+def _bit_length(n):
+    """Bits needed to write the non-negative integer ``n`` (0 for 0), counted
+    up to 8 — enough for every exponent width a FlexFormat can reach. Pure
+    integer compares: exact on every backend, where ``ceil(log2(.))`` is
+    only as exact as the device's logarithm."""
+    return sum((n >= (1 << i)).astype(jnp.int32) for i in range(8))
+
+
 def _needed_e_bits(max_exp, eb: int, fx: int):
     """Smallest e_bits in [eb, eb+fx] whose emax covers ``max_exp``
     (emax(e) = 2**(e-1) - 1). Saturates at eb+fx like the hardware does
     after exhausting its flexible bits."""
-    need = jnp.maximum(max_exp, 0)
-    # e such that 2**(e-1) - 1 >= need  <=>  e >= log2(need+1) + 1
-    e = jnp.ceil(jnp.log2(need.astype(jnp.float32) + 1.0)).astype(jnp.int32) + 1
-    return jnp.clip(e, eb, eb + fx)
+    need = jnp.maximum(jnp.asarray(max_exp), 0).astype(jnp.int32)
+    # e such that 2**(e-1) - 1 >= need  <=>  e - 1 >= bit_length(need)
+    return jnp.clip(_bit_length(need) + 1, eb, eb + fx)
 
 
 def _needed_e_bits_lo(min_exp, eb: int, fx: int):
     """Smallest e_bits in [eb, eb+fx] whose emin reaches DOWN to ``min_exp``
     (emin(e) = 2 - 2**(e-1) <= min_exp), so the value-cluster top stays
     normal instead of flushing — the paper's underflow-adjust trigger."""
-    t = jnp.maximum(2 - min_exp, 1).astype(jnp.float32)
-    e = jnp.ceil(jnp.log2(t)).astype(jnp.int32) + 1
-    return jnp.clip(e, eb, eb + fx)
+    # 2**(e-1) >= 2 - min_exp  <=>  e - 1 >= bit_length(1 - min_exp)
+    t = jnp.maximum(1 - jnp.asarray(min_exp).astype(jnp.int32), 0)
+    return jnp.clip(_bit_length(t) + 1, eb, eb + fx)
 
 
 def select_k(a_max_exp, b_max_exp, fmt: FlexFormat):
@@ -194,10 +199,8 @@ def _tile_max_exp(x, tile_shape: Optional[Tuple[int, ...]]):
     and the reduction is per tile; the broadcast_fn expands a per-tile value
     back to elementwise shape.
     """
-    finite_mag = jnp.where(jnp.isfinite(x), jnp.abs(x), 0.0)
     if tile_shape is None:
-        m = jnp.max(finite_mag)
-        return unbiased_exponent(jnp.maximum(m, jnp.float32(1e-45))), (lambda t: t)
+        return max_exponent(x).reshape(()), (lambda t: t)
 
     if len(tile_shape) != x.ndim:
         raise ValueError(f"tile_shape rank {len(tile_shape)} != operand rank {x.ndim}")
@@ -208,10 +211,8 @@ def _tile_max_exp(x, tile_shape: Optional[Tuple[int, ...]]):
     split = []
     for d, t in zip(x.shape, tile_shape):
         split += [d // t, t]
-    xt = finite_mag.reshape(split)
     red_axes = tuple(range(1, 2 * x.ndim, 2))
-    m = jnp.max(xt, axis=red_axes)
-    me = unbiased_exponent(jnp.maximum(m, jnp.float32(1e-45)))
+    me = jnp.squeeze(max_exponent(x.reshape(split), axis=red_axes), axis=red_axes)
 
     def broadcast(t):
         t = jnp.asarray(t)

@@ -31,7 +31,14 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["DEFAULT_RULES", "active_mesh", "axis_rules", "constrain", "logical_spec"]
+__all__ = [
+    "DEFAULT_RULES",
+    "active_mesh",
+    "axis_rules",
+    "constrain",
+    "logical_spec",
+    "member_spec",
+]
 
 # One entry per logical activation axis: mesh axis name, tuple of names, or
 # None (unconstrained). Axes missing from the live mesh are filtered at
@@ -107,6 +114,21 @@ def active_mesh() -> Optional[Mesh]:
     """
     stack = getattr(_ACTIVE, "stack", None)
     return stack[-1][0] if stack else None
+
+
+def member_spec(n: int) -> Optional[Tuple[Mesh, P]]:
+    """``(mesh, PartitionSpec)`` splitting a leading member dim of extent
+    ``n`` over the active context's ``batch`` axes — the spec a
+    ``shard_map`` over independent members takes — or None outside an
+    :func:`axis_rules` context, or when those axes do not divide ``n``."""
+    stack = getattr(_ACTIVE, "stack", None)
+    if not stack:
+        return None
+    mesh, rules = stack[-1]
+    rule = logical_spec("batch", mesh=mesh, rules=rules)
+    if rule is None or n % _axis_extent(rule, mesh) != 0:
+        return None
+    return mesh, P(rule)
 
 
 def _axis_extent(rule: Rule, mesh: Mesh) -> int:

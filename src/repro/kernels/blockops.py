@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.flexformat import quantize_em, unbiased_exponent
+from repro.core.flexformat import max_exponent, quantize_em
 from repro.core.r2f2 import product_guard_bits, select_k, select_k_op
 
 __all__ = [
@@ -31,9 +31,11 @@ __all__ = [
 
 
 def block_max_exp(t):
-    """Max unbiased exponent over one VMEM block (finite values only)."""
-    mag = jnp.where(jnp.isfinite(t), jnp.abs(t), 0.0)
-    return unbiased_exponent(jnp.maximum(jnp.max(mag), jnp.float32(1e-38)))
+    """Max unbiased exponent over one VMEM block (finite values only), as a
+    ``(1, ..., 1)`` vector of the block's rank — everything derived from it
+    (the split ``k``, the format widths) stays a vector, which is what the
+    TPU kernel compiler can bit-cast."""
+    return max_exponent(t)
 
 
 def rr_mul_block(a, b, fmt, tail_approx, *, exps=None, k_min=None, k_fixed=None):

@@ -64,7 +64,7 @@ from repro.pack.packed import (
     unpack_block,
 )
 from repro.precision.fusion import fused_family
-from repro.profile.capture import pair_exp_hist
+from repro.profile.capture import block_pair_exp_hist
 
 __all__ = ["on_tpu", "resolve_interpret", "FusedOps", "fused_sweep"]
 
@@ -76,8 +76,52 @@ def on_tpu() -> bool:
 def resolve_interpret(interpret: Optional[bool]) -> bool:
     """``None`` -> interpret off TPU, compile to Mosaic on TPU — every
     kernel entry point routes through this, so no call site hard-codes
-    interpreter mode."""
-    return (not on_tpu()) if interpret is None else bool(interpret)
+    interpreter mode. On a TPU backend the kernels always compile: asking
+    for the interpreter there is an error, never a silent slow path."""
+    if on_tpu():
+        if interpret:
+            raise ValueError("Pallas interpret mode requested on a TPU backend")
+        return False
+    return True if interpret is None else bool(interpret)
+
+
+def lane(row, j: int):
+    """Entry ``j`` of a ``(1, n)`` row as a ``(1, 1)`` vector.
+
+    A masked max over the lanes: exact for every value (NaN and -0.0
+    included), and it keeps the entry a vector inside a TPU kernel, where
+    per-site values must not become scalars."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    if jnp.issubdtype(row.dtype, jnp.integer):
+        low = jnp.iinfo(row.dtype).min
+    else:
+        low = -jnp.inf
+    return jnp.max(jnp.where(lanes == j, row, low), axis=1, keepdims=True)
+
+
+def row_of(cols, dtype):
+    """Assemble ``(1, 1)`` vectors into one ``(1, n)`` row (inverse of
+    :func:`lane`)."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, len(cols)), 1)
+    out = jnp.zeros((1, len(cols)), dtype)
+    for j, c in enumerate(cols):
+        out = jnp.where(lanes == j, jnp.asarray(c, dtype), out)
+    return out
+
+
+def evidence_row(ops: "FusedOps"):
+    """One substep's per-site evidence as an ``(n_sites, 2)`` f32 block —
+    built by masked selects so that it is written as one full store (the
+    TPU kernel compiler has no scatter)."""
+    n = len(ops.sites)
+    site = jax.lax.broadcasted_iota(jnp.int32, (n, 2), 0)
+    opnd = jax.lax.broadcasted_iota(jnp.int32, (n, 2), 1)
+    out = jnp.zeros((n, 2), jnp.float32)
+    for j, name in enumerate(ops.sites):
+        ae, be = ops.evidence[name]
+        out = jnp.where((site == j) & (opnd == 0), ae, out)
+        out = jnp.where((site == j) & (opnd == 1), be, out)
+    return out
 
 
 class FusedOps:
@@ -110,14 +154,14 @@ class FusedOps:
                 f"mode {prec.mode!r} has no fused arithmetic family; "
                 "run it on the reference execution path"
             )
-        self.k_floor = k_floor  # (n_sites,) int32 carried splits, or None
+        self.k_floor = k_floor  # per-site (1, 1) int32 carried splits, or None
         self.collect = collect
         self.capture = capture  # CaptureSpec: widen evidence to binned counts
         #: (row_ok (br,1)|None, col_ok (1,bw)|None, br, bw) — this block's
         #: valid-lane masks when the grid is padded; capture counts only
         #: valid lanes, so pad constants can never contaminate a profile
         self.valid = valid
-        self.evidence = {}  # site -> (a_max_exp, b_max_exp) f32 scalars
+        self.evidence = {}  # site -> (a_max_exp, b_max_exp) f32 (1, 1) vectors
         self.counts = {}  # site -> (2, n_bins) int32 operand exponent counts
 
     def _valid_mask(self, shape):
@@ -175,7 +219,9 @@ class FusedOps:
                 raise ValueError(f"fused body hit site {site!r} twice in one substep")
             self.evidence[site] = tuple(e.astype(jnp.float32) for e in exps)
         if self.capture is not None:
-            self.counts[site] = pair_exp_hist(a, b, self.capture, self._valid_mask(shape))
+            self.counts[site] = block_pair_exp_hist(
+                a, b, self.capture, self._valid_mask(shape)
+            )
         return a, b, exps
 
     def _k_floor_at(self, site: str):
@@ -260,9 +306,11 @@ def _sweep_kernel(
     else:
         state_refs = refs[:n_state]
         pos = n_state
+    n_sites = len(sites)
     k_floor = None
     if has_floor:
-        k_floor = refs[pos][...][0]  # (n_sites,) int32
+        row = refs[pos][...]  # (1, n_sites) int32
+        k_floor = tuple(lane(row, j) for j in range(n_sites))
         pos += 1
     if packed:
         out_refs = refs[pos : pos + n_out]
@@ -281,16 +329,15 @@ def _sweep_kernel(
     if packed:
         # prologue: unpack each leaf at its carried storage split
         state = tuple(
-            unpack_block(pr[...], prec.fmt, kr[...][0, 0])
+            unpack_block(pr[...], prec.fmt, kr[...])
             for pr, kr in zip(pay_refs, ks_refs)
         )
     else:
         state = tuple(r[...] for r in state_refs)
-    n_sites = len(sites)
-    # evidence/counts carried functionally through the substep loop, written once
-    ev0 = jnp.zeros((steps, n_sites, 2) if collect else (1,), jnp.float32)
+    # counts carried functionally through the substep loop, written once;
+    # evidence is stored one full (n_sites, 2) row per substep
     cnt0 = jnp.zeros(
-        (n_sites, 2, capture.n_bins) if capture is not None else (1,), jnp.int32
+        (2 * n_sites, capture.n_bins) if capture is not None else (1,), jnp.int32
     )
 
     # valid-lane masks for capture on padded grids: this block's global row/
@@ -309,7 +356,7 @@ def _sweep_kernel(
         valid = (row_ok, col_ok, br, bw)
 
     def substep(s, carry):
-        st, ev, cnt = carry
+        st, cnt = carry
         ops = FusedOps(
             prec, sites, k_floor=k_floor, collect=collect, capture=capture,
             valid=valid, site_ops=site_ops,
@@ -326,26 +373,23 @@ def _sweep_kernel(
             missing = [n for n in sites if n not in ops.evidence]
             if missing:
                 raise ValueError(f"fused body never multiplied at sites {missing}")
-            for j, name in enumerate(sites):
-                ae, be = ops.evidence[name]
-                ev = ev.at[s, j, 0].set(ae)
-                ev = ev.at[s, j, 1].set(be)
+            ev_ref[0, 0, pl.ds(s, 1)] = evidence_row(ops)[None]
         if capture is not None:
             # the widened evidence: substep counts accumulate over the chunk
-            cnt = cnt + jnp.stack([ops.counts[name] for name in sites])
-        return new, ev, cnt
+            cnt = cnt + jnp.concatenate([ops.counts[name] for name in sites], axis=0)
+        return new, cnt
 
     if steps == 1:
         # single-substep bodies (e.g. an elementwise flux) may return fewer
         # leaves than they take — no loop carry to keep structurally stable
-        state, ev, cnt = substep(0, (state, ev0, cnt0))
+        state, cnt = substep(0, (state, cnt0))
     else:
         if n_out != n_state:
             raise ValueError(
                 f"multi-substep sweeps need body in/out leaf counts to match "
                 f"({n_state} != {n_out}): the output is the next substep's input"
             )
-        state, ev, cnt = jax.lax.fori_loop(0, steps, substep, (state, ev0, cnt0))
+        state, cnt = jax.lax.fori_loop(0, steps, substep, (state, cnt0))
     if packed:
         # epilogue: re-pick each leaf's storage split from the advanced
         # values and encode — identical math to repro.pack's XLA-boundary
@@ -353,14 +397,12 @@ def _sweep_kernel(
         for pr, kr, v in zip(out_refs, kout_refs, state):
             k_st = block_storage_k(v, prec.fmt)
             pr[...] = pack_block(v, prec.fmt, k_st).astype(payload_dtype(prec.fmt))
-            kr[...] = jnp.reshape(k_st, (1, 1)).astype(jnp.int32)
+            kr[...] = k_st.astype(jnp.int32)
     else:
         for r, v in zip(out_refs, state):
             r[...] = v
-    if collect:
-        ev_ref[...] = ev[None, None]  # (1, 1, steps, n_sites, 2) block
     if capture is not None:
-        cnt_ref[...] = cnt[None, None]  # (1, 1, n_sites, 2, n_bins) block
+        cnt_ref[...] = cnt[None, None]  # (1, 1, 2 * n_sites, n_bins) block
 
 
 def fused_sweep(
@@ -519,9 +561,9 @@ def fused_sweep(
     if capture is not None:
         nb = capture.n_bins
         out_specs.append(
-            pl.BlockSpec((1, 1, n_sites, 2, nb), lambda i, j: (i, j, 0, 0, 0))
+            pl.BlockSpec((1, 1, 2 * n_sites, nb), lambda i, j: (i, j, 0, 0))
         )
-        out_shape.append(jax.ShapeDtypeStruct((gi, gj, n_sites, 2, nb), jnp.int32))
+        out_shape.append(jax.ShapeDtypeStruct((gi, gj, 2 * n_sites, nb), jnp.int32))
 
     call = pl.pallas_call(
         functools.partial(
@@ -558,6 +600,7 @@ def fused_sweep(
     if capture is not None:
         # global counts = sum of per-block counts (blocks partition elements)
         counts = jnp.sum(outs.pop(), axis=(0, 1), dtype=jnp.int32)
+        counts = counts.reshape(n_sites, 2, capture.n_bins)
     evidence = None
     if collect_evidence:
         # the global per-substep site evidence is the max over blocks (max of

@@ -53,7 +53,7 @@ import repro.obs as _obs
 
 from repro.core.flexformat import quantize_em
 from repro.core.policy import RangeTracker, adjust_step
-from repro.kernels.fused import FusedOps, resolve_interpret
+from repro.kernels.fused import FusedOps, evidence_row, lane, resolve_interpret, row_of
 from repro.pack.packed import (
     PackedArray,
     _view2d,
@@ -113,7 +113,7 @@ def _mega_kernel(
         ks_refs = refs[pos + n_state : pos + 2 * n_state]
         pos += 2 * n_state
         state = tuple(
-            unpack_block(pr[...], fmt, kr[...][0, 0])
+            unpack_block(pr[...], fmt, kr[...])
             for pr, kr in zip(pay_refs, ks_refs)
         )
     else:
@@ -121,26 +121,31 @@ def _mega_kernel(
         pos += n_state
     trk0 = ()
     k_active = None
+
+    def cols(ref):
+        row = ref[...]  # (1, n_sites)
+        return tuple(lane(row, j) for j in range(n_sites))
+
     if evolve:
-        # the adjust unit's carried state — scalar rows living in registers
-        k0, hi0, lo0, ov0, sh0 = (refs[pos + i][...][0] for i in range(5))
+        # the adjust unit's carried state — per site, one (1, 1) vector per
+        # field: (k, hi_ema, lo_ema, overflow_steps, shrink_steps)
+        trk0 = tuple(cols(refs[pos + i]) for i in range(5))
         pos += 5
-        trk0 = (k0.astype(jnp.int32), hi0, lo0, ov0.astype(jnp.int32), sh0.astype(jnp.int32))
         k_active = trk0[0]  # datapath floor, latched at snapshot boundaries
     elif has_floor:
-        k_active = refs[pos][...][0]  # pinned: static profiled splits
+        k_active = cols(refs[pos])  # pinned: static profiled splits
         pos += 1
 
     # ---- output refs -----------------------------------------------------
     out_refs = refs[pos : pos + n_state]
     pos += n_state
-    kout_refs = kst_ref = None
+    kout_refs = kst_refs = None
     if packed_io:
         kout_refs = refs[pos : pos + n_state]
         pos += n_state
     elif storage == "packed":
-        kst_ref = refs[pos]
-        pos += 1
+        kst_refs = refs[pos : pos + n_state]
+        pos += n_state
     snap_refs = ()
     if n_out > 0:
         snap_refs = refs[pos : pos + n_state]
@@ -166,20 +171,22 @@ def _mega_kernel(
         raw values, via the shared block helpers (same splits, same bits)."""
         qs, ks = [], []
         for v in st:
-            kb = block_storage_k(v, fmt)
+            # quantize with the split at the leaf's rank (a (1, 1) split
+            # broadcast over a rank-3 leaf is a layout the TPU kernel
+            # compiler refuses); carry it as (1, 1), folding leading axes
+            kb = block_storage_k(v, fmt).astype(jnp.int32)
             qs.append(quantize_em(v, fmt.eb + kb, fmt.mb + fmt.fx - kb))
-            ks.append(kb)
-        return tuple(qs), jnp.stack(ks).astype(jnp.int32)
+            ks.append(jnp.max(kb, axis=tuple(range(kb.ndim - 2))) if kb.ndim > 2 else kb)
+        return tuple(qs), tuple(ks)
 
-    ev0 = jnp.zeros((steps, n_sites, 2) if emit_ev else (1,), jnp.float32)
     cnt0 = jnp.zeros(
-        (n_sites, 2, capture.n_bins) if capture is not None else (1,), jnp.int32
+        (2 * n_sites, capture.n_bins) if capture is not None else (1,), jnp.int32
     )
-    kst0 = jnp.zeros((n_state,), jnp.int32)
-    ka0 = k_active if evolve else jnp.zeros((1,), jnp.int32)
+    kst0 = tuple(jnp.zeros((1, 1), jnp.int32) for _ in state)
+    ka0 = k_active if evolve else ()
 
     def substep(s, carry):
-        st, trk, ka, ev, cnt, cnt_last, kst = carry
+        st, trk, ka, cnt, cnt_last, kst = carry
         floor = (ka if evolve else k_active) if (evolve or has_floor) else None
         ops = FusedOps(
             prec, sites, k_floor=floor, collect=collect, capture=capture,
@@ -198,7 +205,7 @@ def _mega_kernel(
             if missing:
                 raise ValueError(f"mega body never hit sites {missing}")
         if evolve:
-            # the on-chip adjust unit: one scalar tick per site, this substep
+            # the on-chip adjust unit: one tick per site, this substep
             k_a, hi_a, lo_a, ov_a, sh_a = trk
             rows = []
             for j, name in enumerate(sites):
@@ -211,25 +218,22 @@ def _mega_kernel(
                         ae, be, prec, op, k_bounds=kb,
                     )
                 )
-            trk = tuple(jnp.stack(col) for col in zip(*rows))
+            trk = tuple(zip(*rows))
         if emit_ev:
-            for j, name in enumerate(sites):
-                ae, be = ops.evidence[name]
-                ev = ev.at[s, j, 0].set(ae)
-                ev = ev.at[s, j, 1].set(be)
+            ev_ref[pl.ds(s, 1)] = evidence_row(ops)[None]
         if capture is not None:
-            cnt = cnt + jnp.stack([ops.counts[name] for name in sites])
+            cnt = cnt + jnp.concatenate([ops.counts[name] for name in sites], axis=0)
 
         boundary = ((s + 1) % every) == 0
         if rounding:
             qs, ks = _round_all(new)
             new = tuple(jnp.where(boundary, q, v) for q, v in zip(qs, new))
-            kst = jnp.where(boundary, ks, kst)
+            kst = tuple(jnp.where(boundary, k, k0) for k, k0 in zip(ks, kst))
         if evolve:
             # latch the datapath floor at the chunk cadence — the substeps
             # between boundaries run at the same splits the chunked plane's
             # between-chunk fold would hand the next kernel call
-            ka = jnp.where(boundary, trk[0], ka)
+            ka = tuple(jnp.where(boundary, k, k0) for k, k0 in zip(trk[0], ka))
         if n_out > 0:
             idx = (s + 1) // every - 1
 
@@ -242,10 +246,10 @@ def _mega_kernel(
 
             if capture is not None:
                 cnt_last = jnp.where(boundary, cnt, cnt_last)
-        return new, trk, ka, ev, cnt, cnt_last, kst
+        return new, trk, ka, cnt, cnt_last, kst
 
-    carry = (state, trk0, ka0, ev0, cnt0, cnt0, kst0)
-    state, trk, _ka, ev, cnt, _cl, kst = jax.lax.fori_loop(0, steps, substep, carry)
+    carry = (state, trk0, ka0, cnt0, cnt0, kst0)
+    state, trk, _ka, cnt, _cl, kst = jax.lax.fori_loop(0, steps, substep, carry)
 
     rem = steps - n_out * every
     if rem and rounding:
@@ -258,17 +262,16 @@ def _mega_kernel(
             # packing at the SAME carried split reproduces the chunked
             # plane's pack-from-raw bits exactly
             pr[...] = pack_block(state[i], fmt, kst[i]).astype(payload_dtype(fmt))
-            kr[...] = jnp.reshape(kst[i], (1, 1)).astype(jnp.int32)
+            kr[...] = kst[i]
     else:
         for r, v in zip(out_refs, state):
             r[...] = v
-        if kst_ref is not None:
-            kst_ref[...] = kst[None]
+        if kst_refs is not None:
+            for r, k in zip(kst_refs, kst):
+                r[...] = k
     if evolve:
         for r, v in zip(trk_out, trk):
-            r[...] = v[None]
-    if emit_ev:
-        ev_ref[...] = ev
+            r[...] = row_of(v, r.dtype)
     if capture is not None:
         cnt_ref[...] = cnt
 
@@ -374,7 +377,7 @@ def mega_sweep(
     else:
         out_shape += [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
         if storage == "packed":
-            out_shape.append(jax.ShapeDtypeStruct((1, n_state), jnp.int32))
+            out_shape += [jax.ShapeDtypeStruct((1, 1), jnp.int32)] * n_state
     if n_out > 0:
         out_shape += [jax.ShapeDtypeStruct((n_out,) + s, jnp.float32) for s in shapes]
     if evolve:
@@ -389,9 +392,10 @@ def mega_sweep(
         out_shape.append(jax.ShapeDtypeStruct((steps, n_sites, 2), jnp.float32))
     if capture is not None:
         nb = capture.n_bins
-        out_shape.append(jax.ShapeDtypeStruct((n_sites, 2, nb), jnp.int32))
+        # counts ride the kernel as (2 * n_sites, n_bins) rows
+        out_shape.append(jax.ShapeDtypeStruct((2 * n_sites, nb), jnp.int32))
         if n_out > 0:
-            out_shape.append(jax.ShapeDtypeStruct((n_out, n_sites, 2, nb), jnp.int32))
+            out_shape.append(jax.ShapeDtypeStruct((n_out, 2 * n_sites, nb), jnp.int32))
 
     call = (
         pl.pallas_call(
@@ -451,7 +455,7 @@ def mega_sweep(
             for p, kk, pa in zip(outs[:n_state], kouts, pas)
         )
     elif storage == "packed":
-        kst = outs[n_state][0]
+        kst = [k.reshape(()) for k in outs[n_state : 2 * n_state]]
         final = []
         for i, q in enumerate(outs[:n_state]):
             view = _view2d(shapes[i])
@@ -471,9 +475,9 @@ def mega_sweep(
 
     exp_time = exp_total = None
     if capture is not None:
-        exp_total = total_cnt
+        exp_total = total_cnt.reshape(n_sites, 2, capture.n_bins)
         exp_time = (
-            time_cnt
+            time_cnt.reshape(n_out, n_sites, 2, capture.n_bins)
             if time_cnt is not None
             else jnp.zeros((0, n_sites, 2, capture.n_bins), jnp.int32)
         )
@@ -530,18 +534,15 @@ def heat2d_mega(
     """Whole-horizon 2-D heat sweep; ``u0`` is the (nx, ny) field."""
     from repro.kernels.pde_steps import _heat2d_body
 
-    packed = isinstance(u0, PackedArray)
-    nx, ny = u0.shape
-    lead = u0.with_view((1, nx * ny)) if packed else u0.reshape(1, nx * ny)
+    lead = u0 if isinstance(u0, PackedArray) else jnp.asarray(u0, jnp.float32)
     res = mega_sweep(
-        _heat2d_body(nx, ny, float(alpha), float(dtodx2), sites),
+        _heat2d_body(float(alpha), float(dtodx2), sites),
         (lead,),
         prec=prec, sites=sites, steps=steps, every=every, tracker=tracker,
         collect_evidence=collect_evidence, capture=capture, interpret=interpret,
         storage=storage,
     )
-    unwrap = (lambda o: o.with_view((nx, ny))) if packed else (lambda o: o.reshape(nx, ny))
-    return _single_leaf(res, unwrap, (nx, ny))
+    return _single_leaf(res, lambda o: o, tuple(u0.shape))
 
 
 @functools.partial(jax.jit, static_argnames=_MEGA_STATICS + ("speed", "dtodx"))

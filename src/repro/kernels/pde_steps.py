@@ -11,10 +11,9 @@ order, same f32 adds), which is what makes the fused and reference paths
 bit-identical whenever a block covers the whole field.
 
 Layout notes: the 1-D periodic workloads keep the whole rod in-block (the
-rolls are in-register); the 2-D heat field rides flattened as one
-``(1, nx*ny)`` leaf and is reshaped inside the body — the coupled extent
-never crosses a block boundary, so there is no inter-block halo to
-exchange.
+rolls are in-register); the 2-D heat field is one ``(nx, ny)`` block — the
+coupled extent never crosses a block boundary, so there is no inter-block
+halo to exchange.
 """
 
 from __future__ import annotations
@@ -36,12 +35,11 @@ BURGERS1D_SITES = ("burgers.uu", "burgers.flux")
 # ---------------------------------------------------------------------------
 
 
-def _heat2d_body(nx, ny, alpha, dtodx2, sites):
+def _heat2d_body(alpha, dtodx2, sites):
     flux_site, update_site = sites
 
     def body(state, ops):
-        (uf,) = state
-        u = uf.reshape(nx, ny)
+        (u,) = state
         lap = (  # 5-point interior laplacian, adds in f32
             u[:-2, 1:-1]
             + u[2:, 1:-1]
@@ -51,8 +49,10 @@ def _heat2d_body(nx, ny, alpha, dtodx2, sites):
         )
         flux = ops.mul(jnp.float32(alpha), lap, flux_site)
         upd = ops.mul(flux, jnp.float32(dtodx2), update_site)
-        u = u.at[1:-1, 1:-1].add(upd)
-        return (u.reshape(1, nx * ny),)
+        # the interior update, framed by the Dirichlet edges (concatenation:
+        # the TPU kernel compiler has no scatter for ``.at[].add``)
+        mid = jnp.concatenate([u[1:-1, :1], u[1:-1, 1:-1] + upd, u[1:-1, -1:]], axis=1)
+        return (jnp.concatenate([u[:1], mid, u[-1:]], axis=0),)
 
     return body
 
@@ -82,19 +82,16 @@ def heat2d_sweep(
 
     Returns ``(u, evidence)`` (+ exponent counts when ``capture`` is set).
     ``storage="packed"`` takes and returns the field as a single-block
-    :class:`repro.pack.PackedArray`, re-viewed to the kernel's flattened
-    ``(1, nx*ny)`` leaf (same split either way — one block).
+    :class:`repro.pack.PackedArray`.
     """
-    packed = storage == "packed"
     nx, ny = u0.shape
-    lead = u0.with_view((1, nx * ny)) if packed else u0.reshape(1, nx * ny)
     res = fused.fused_sweep(
-        _heat2d_body(nx, ny, float(alpha), float(dtodx2), sites),
-        (lead,),
+        _heat2d_body(float(alpha), float(dtodx2), sites),
+        (u0,),
         prec=prec,
         sites=sites,
         steps=steps,
-        block=(1, nx * ny),
+        block=(nx, ny),
         k_floor=k_floor,
         collect_evidence=collect_evidence,
         capture=capture,
@@ -103,9 +100,9 @@ def heat2d_sweep(
     )
     if capture is not None:
         (out,), ev, counts = res
-        return (out.with_view((nx, ny)) if packed else out.reshape(nx, ny)), ev, counts
+        return out, ev, counts
     (out,), ev = res
-    return (out.with_view((nx, ny)) if packed else out.reshape(nx, ny)), ev
+    return out, ev
 
 
 # ---------------------------------------------------------------------------

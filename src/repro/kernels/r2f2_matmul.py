@@ -29,8 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 import repro.obs as _obs
-from repro.core.flexformat import quantize_em, unbiased_exponent
+from repro.core.flexformat import quantize_em
 from repro.core.r2f2 import product_guard_bits, select_k
+from repro.kernels.blockops import block_max_exp
+from repro.kernels.fused import resolve_interpret
 
 DEFAULT_BLOCKS = (128, 128, 128)
 
@@ -42,12 +44,7 @@ def _matmul_kernel(a_ref, b_ref, o_ref, *, fmt, round_products, tail_approx):
 
     a = a_ref[...]
     b = b_ref[...]
-
-    def tile_max_exp(t):
-        mag = jnp.where(jnp.isfinite(t), jnp.abs(t), 0.0)
-        return unbiased_exponent(jnp.maximum(jnp.max(mag), jnp.float32(1e-38)))
-
-    k = select_k(tile_max_exp(a), tile_max_exp(b), fmt)
+    k = select_k(block_max_exp(a), block_max_exp(b), fmt)  # (1, 1)
     e_bits = fmt.eb + k
     m_bits = fmt.mb + fmt.fx - k
     aq = quantize_em(a, e_bits, m_bits)
@@ -78,7 +75,7 @@ def r2f2_matmul_pallas(
     blocks=DEFAULT_BLOCKS,
     round_products=False,
     tail_approx=True,
-    interpret=True,
+    interpret=None,
 ):
     """C = A @ B with R2F2 block semantics. A: (M, K) f32, B: (K, N) f32.
 
@@ -118,7 +115,7 @@ def r2f2_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     with _obs.span("pallas.r2f2_matmul", m=m, n=n, k=kdim):
         _obs.inc(

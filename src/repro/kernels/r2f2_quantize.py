@@ -23,27 +23,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 import repro.obs as _obs
-from repro.core.flexformat import quantize_em, unbiased_exponent
+from repro.core.flexformat import quantize_em
 from repro.core.r2f2 import select_k_operand
+from repro.kernels.blockops import block_max_exp
+from repro.kernels.fused import resolve_interpret
 
 DEFAULT_BLOCK = (256, 256)
 
 
 def _quantize_kernel(x_ref, y_ref, k_ref, *, fmt):
     x = x_ref[...]
-    mag = jnp.where(jnp.isfinite(x), jnp.abs(x), 0.0)
-    me = unbiased_exponent(jnp.maximum(jnp.max(mag), jnp.float32(1e-38)))
     # operand-only need: product bound handled by the consumer's shared-k
-    k = select_k_operand(me, fmt)
+    k = select_k_operand(block_max_exp(x), fmt)  # (1, 1)
     e_bits = fmt.eb + k
     m_bits = fmt.mb + fmt.fx - k
     y_ref[...] = quantize_em(x, e_bits, m_bits)
-    k_ref[0, 0] = k
+    k_ref[...] = k[None, None]
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "block", "interpret"))
-def r2f2_quantize_pallas(x, *, fmt, block=DEFAULT_BLOCK, interpret=True):
-    """Quantize a 2D f32 array tile-by-tile. Returns (y, k_tiles)."""
+def r2f2_quantize_pallas(x, *, fmt, block=DEFAULT_BLOCK, interpret=None):
+    """Quantize a 2D f32 array tile-by-tile. Returns (y, k_tiles).
+
+    Each tile's split is written as its own ``(1, 1)`` trailing block of a
+    ``(gm, gn, 1, 1)`` array — a block the TPU compiler accepts, where a
+    ``(1, 1)`` block of a ``(gm, gn)`` array is not (8, 128)-aligned."""
     m, n = x.shape
     bm = min(block[0], m)
     bn = min(block[1], n)
@@ -56,13 +60,13 @@ def r2f2_quantize_pallas(x, *, fmt, block=DEFAULT_BLOCK, interpret=True):
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, 1, 1), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     with _obs.span("pallas.r2f2_quantize", m=m, n=n):
         _obs.inc(
@@ -71,4 +75,4 @@ def r2f2_quantize_pallas(x, *, fmt, block=DEFAULT_BLOCK, interpret=True):
             kernel="r2f2_quantize",
         )
         y, k = call(x.astype(jnp.float32))
-    return y, k
+    return y, k[:, :, 0, 0]

@@ -16,7 +16,7 @@ from repro.core.r2f2 import product_guard_bits, select_k, select_k_op, select_k_
 
 def _max_exp(t):
     mag = jnp.where(jnp.isfinite(t), jnp.abs(t), 0.0)
-    return unbiased_exponent(jnp.maximum(jnp.max(mag), jnp.float32(1e-38)))
+    return unbiased_exponent(jnp.max(mag))
 
 
 def _operand_k(t, fmt):
@@ -32,7 +32,7 @@ def r2f2_quantize_ref(x, *, fmt, block=(256, 256)):
     gm, gn = m // bm, n // bn
     xt = x.reshape(gm, bm, gn, bn)
     mag = jnp.where(jnp.isfinite(xt), jnp.abs(xt), 0.0)
-    me = unbiased_exponent(jnp.maximum(jnp.max(mag, axis=(1, 3)), jnp.float32(1e-38)))
+    me = unbiased_exponent(jnp.max(mag, axis=(1, 3)))
     k = select_k_operand(me, fmt)
     kb = k[:, None, :, None]
     y = quantize_em(xt, fmt.eb + kb, fmt.mb + fmt.fx - kb)

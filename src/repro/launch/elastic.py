@@ -10,7 +10,9 @@ the per-host batch slices change.
 
 spawns itself with ``xla_force_host_platform_device_count=<devices>`` and
 continues training on the new mesh (examples/elastic_restart.py demos the
-full failure -> shrink -> resume cycle).
+full failure -> shrink -> resume cycle). The child runs on virtual CPU
+devices (``JAX_PLATFORMS=cpu``): this is a CPU tool, and a child that
+reached for an accelerator its parent may hold would fail or hang.
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ def respawn_with_devices(n_devices: int, argv):
         f"--xla_force_host_platform_device_count={n_devices} "
         + env.get("XLA_FLAGS", "")
     )
+    env["JAX_PLATFORMS"] = "cpu"
     env["REPRO_ELASTIC_CHILD"] = "1"
     cmd = [sys.executable, "-m", "repro.launch.elastic"] + argv
     return subprocess.run(cmd, env=env).returncode

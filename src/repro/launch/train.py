@@ -32,6 +32,7 @@ from repro.configs import get_config, reduced
 from repro.precision import PRESETS
 from repro.data import batch_for_step
 from repro.dist.sharding import axis_rules
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train import OptConfig, TrainConfig, init_train_state, make_train_step
 
@@ -56,6 +57,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=17)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

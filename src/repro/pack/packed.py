@@ -35,9 +35,9 @@ import jax.numpy as jnp
 
 from repro.core.flexformat import (
     FlexFormat,
+    max_exponent,
     pack_r2f2,
     quantize_em,
-    unbiased_exponent,
     unpack_r2f2,
 )
 from repro.core.r2f2 import select_k_operand
@@ -69,10 +69,9 @@ def block_storage_k(x, fmt: FlexFormat, k_min: int = 0):
     """Storage split for one 2-D block: minimal k representing the block's
     finite value-cluster top as a normal (zeros and non-finites excluded,
     empty blocks floor at exponent -127 -> widest-coverage-downward split is
-    clamped by ``k_min``)."""
-    mag = jnp.where(jnp.isfinite(x), jnp.abs(jnp.asarray(x, jnp.float32)), 0.0)
-    me = unbiased_exponent(jnp.maximum(jnp.max(mag), jnp.float32(1e-38)))
-    return jnp.clip(select_k_operand(me, fmt), k_min, fmt.fx)
+    clamped by ``k_min``). The split comes back as a ``(1, 1)`` vector for a
+    2-D block (see :func:`repro.core.flexformat.max_exponent`)."""
+    return jnp.clip(select_k_operand(max_exponent(x), fmt), k_min, fmt.fx)
 
 
 def pack_block(x, fmt: FlexFormat, k):
@@ -200,8 +199,7 @@ def pack_array(
 
     # (gi, br, gj, bw) tiling; one split per (gi, gj) block
     xt = x2.reshape(gi, br, gj, bw)
-    mag = jnp.where(jnp.isfinite(xt), jnp.abs(xt), 0.0)
-    me = unbiased_exponent(jnp.maximum(jnp.max(mag, axis=(1, 3)), jnp.float32(1e-38)))
+    me = max_exponent(xt, axis=(1, 3))[:, 0, :, 0]
     k = jnp.clip(select_k_operand(me, fmt), k_min, fmt.fx).astype(jnp.int32)
 
     k_elem = jnp.broadcast_to(k[:, None, :, None], xt.shape)

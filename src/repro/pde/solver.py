@@ -51,7 +51,7 @@ import jax.numpy as jnp
 
 import repro.obs as _obs
 from repro.core.policy import PrecisionConfig
-from repro.dist.sharding import constrain
+from repro.dist.sharding import member_spec
 from repro.pack import is_packed, pack_state, storage_quantize, unpack_state
 from repro.precision import (
     fold_evidence,
@@ -272,17 +272,6 @@ class SimResult(NamedTuple):
     snapshots: Any  # stacked observables, leading dim = n snapshots
     tracker: Optional[Any]  # final SiteTracker (tracked modes)
     profile: Optional[Any] = None  # repro.profile.capture.CaptureResult
-
-
-def _constrain_ensemble(tree):
-    """Annotate every leaf's leading (member) dim as the logical batch axis.
-
-    No-op outside a ``dist.sharding.axis_rules`` context, so unsharded
-    ensembles and unit tests run mesh-free.
-    """
-    return jax.tree_util.tree_map(
-        lambda x: constrain(x, "batch", *([None] * (x.ndim - 1))), tree
-    )
 
 
 @dataclasses.dataclass
@@ -888,7 +877,12 @@ class Simulation:
         ``batch`` axis, so inside a ``dist.sharding.axis_rules(mesh)``
         context the ensemble spreads over the mesh's data axes — the
         production-scale path for parameter sweeps and uncertainty
-        quantification. ``capture``/``policy`` behave as in :meth:`run`,
+        quantification. The members run under a ``shard_map``: each device
+        advances its own members with no collective, so the Pallas kernels
+        (which the compiler cannot partition) stay local to their device.
+        Outside a context, or when the mesh's batch axes do not divide the
+        member count, the ensemble runs unsharded.
+        ``capture``/``policy`` behave as in :meth:`run`,
         per member (each member gets its own histograms and evidence).
 
         ``tracker0_batch`` resumes tracked modes from a *stacked* tracker
@@ -941,10 +935,6 @@ class Simulation:
         tracker0_batch=None,
         storage: str = "f32",
     ) -> SimResult:
-        if sharded:
-            state0_batch = _constrain_ensemble(state0_batch)
-            if tracker0_batch is not None:
-                tracker0_batch = _constrain_ensemble(tracker0_batch)
         # resolve once outside the vmap so an ineligible explicit "fused"
         # raises eagerly with the real reason rather than from inside a trace
         execution = self._resolve_execution(execution)
@@ -962,12 +952,16 @@ class Simulation:
                 storage=storage,
             )
 
-        if tracker0_batch is not None:
-            res = jax.vmap(one)(state0_batch, tracker0_batch)
-        else:
-            res = jax.vmap(one)(state0_batch)
-        if sharded:
-            # every result leaf (state, snapshots, tracker rows) leads with
-            # the member dim — annotate them all so nothing gets replicated
-            res = _constrain_ensemble(res)
-        return res
+        args = (state0_batch,) if tracker0_batch is None else (state0_batch, tracker0_batch)
+        run = jax.vmap(one)
+        n_members = jax.tree_util.tree_leaves(state0_batch)[0].shape[0]
+        spec = member_spec(n_members) if sharded else None
+        if spec is not None:
+            # every argument and result leaf (state, snapshots, tracker rows)
+            # leads with the member dim: split them all over the batch axes
+            mesh, members = spec
+            run = jax.shard_map(
+                run, mesh=mesh, in_specs=(members,) * len(args), out_specs=members,
+                check_vma=False,
+            )
+        return run(*args)

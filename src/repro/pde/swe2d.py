@@ -105,16 +105,34 @@ def _flux_G(U, mom):
     return jnp.stack([hv, hu * hv / h, mom(hv, h)])
 
 
-def _reflect(U):
-    """Reflective walls: zero normal momentum at boundaries, mirror h."""
-    h, hu, hv = U[0], U[1], U[2]
-    h = h.at[0, :].set(h[1, :]).at[-1, :].set(h[-2, :])
-    h = h.at[:, 0].set(h[:, 1]).at[:, -1].set(h[:, -2])
-    hu = hu.at[0, :].set(-hu[1, :]).at[-1, :].set(-hu[-2, :])
-    hu = hu.at[:, 0].set(hu[:, 1]).at[:, -1].set(hu[:, -2])
-    hv = hv.at[:, 0].set(-hv[:, 1]).at[:, -1].set(-hv[:, -2])
-    hv = hv.at[0, :].set(hv[1, :]).at[-1, :].set(hv[-2, :])
-    return jnp.stack([h, hu, hv])
+def _edge_rows(a, negate=False):
+    """Frame ``a`` with a copy of its first and last rows (negated for a
+    reflected normal momentum)."""
+    top, bottom = a[:1], a[-1:]
+    if negate:
+        top, bottom = -top, -bottom
+    return jnp.concatenate([top, a, bottom], axis=0)
+
+
+def _edge_cols(a, negate=False):
+    """Frame ``a`` with a copy of its first and last columns."""
+    left, right = a[:, :1], a[:, -1:]
+    if negate:
+        left, right = -left, -right
+    return jnp.concatenate([left, a, right], axis=1)
+
+
+def _reflect(interior):
+    """Reflective walls around the updated interior ``(3, nx-2, ny-2)``:
+    zero normal momentum at the boundaries, mirrored h. Every boundary
+    value is a copy (or negation) of an interior neighbour, so the walls
+    are built by concatenation (the TPU kernel compiler has no scatter)."""
+    h, hu, hv = interior[0], interior[1], interior[2]
+    return jnp.stack([
+        _edge_cols(_edge_rows(h)),
+        _edge_cols(_edge_rows(hu, negate=True)),
+        _edge_rows(_edge_cols(hv, negate=True)),
+    ])
 
 
 _F32 = PrecisionConfig(mode="f32")
@@ -145,8 +163,7 @@ def _lw_step(U, cfg: SWEConfig, mom):
         - (dt / dx) * (Fx[:, 1:, 1:-1] - Fx[:, :-1, 1:-1])
         - (dt / dy) * (Gy[:, 1:-1, 1:] - Gy[:, 1:-1, :-1])
     )
-    U = U.at[:, 1:-1, 1:-1].set(interior)
-    return _reflect(U)
+    return _reflect(interior)
 
 
 @register_stepper("swe2d")

@@ -33,12 +33,20 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.flexformat import unbiased_exponent
 from repro.core.policy import _site_max_exp
 
-__all__ = ["CaptureSpec", "CaptureResult", "exp_hist", "pair_exp_hist", "site_evidence"]
+__all__ = [
+    "CaptureSpec",
+    "CaptureResult",
+    "exp_hist",
+    "pair_exp_hist",
+    "block_pair_exp_hist",
+    "site_evidence",
+]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +117,32 @@ def pair_exp_hist(a, b, spec: CaptureSpec, mask=None) -> jnp.ndarray:
     """Bin both operands of one multiplication (already broadcast to a
     common shape by the caller). Returns ``(2, n_bins) int32``."""
     return jnp.stack([exp_hist(a, spec, mask), exp_hist(b, spec, mask)])
+
+
+def _block_hist_row(x, spec: CaptureSpec, mask=None) -> jnp.ndarray:
+    """:func:`exp_hist` of one 2-D kernel block as a ``(1, n_bins)`` row:
+    one masked count per bin. The TPU kernel compiler lowers this form,
+    where it refuses :func:`exp_hist`'s flatten-and-one-hot; the counts are
+    the same (the capture parity tests hold the kernel planes to the
+    reference plane's :func:`exp_hist`)."""
+    keep = jnp.isfinite(x) & (x != 0.0)
+    if mask is not None:
+        keep = keep & mask
+    idx = jnp.clip(unbiased_exponent(x) - spec.e_lo, 0, spec.n_bins - 1)
+    bins = jax.lax.broadcasted_iota(jnp.int32, (1, spec.n_bins), 1)
+    out = jnp.zeros((1, spec.n_bins), jnp.int32)
+    for b in range(spec.n_bins):
+        count = jnp.sum(jnp.where((idx == b) & keep, 1, 0), keepdims=True)
+        out = jnp.where(bins == b, count, out)
+    return out
+
+
+def block_pair_exp_hist(a, b, spec: CaptureSpec, mask=None) -> jnp.ndarray:
+    """:func:`pair_exp_hist` inside a kernel: both operands of one
+    multiplication (2-D blocks of one shape), ``(2, n_bins) int32``."""
+    return jnp.concatenate(
+        [_block_hist_row(a, spec, mask), _block_hist_row(b, spec, mask)], axis=0
+    )
 
 
 def site_evidence(a, b) -> jnp.ndarray:

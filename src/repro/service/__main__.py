@@ -24,6 +24,7 @@ import sys
 
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.pde import known_steppers
 
 from .request import SimRequest, scaled_state0
@@ -49,6 +50,7 @@ def main(argv=None) -> int:
     ap.add_argument("--shadow-rate", type=float, default=0.25,
                     help="--health shadow-oracle sampling rate")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = args.steppers.split(",") if args.steppers else known_steppers()
     steps = 48 if args.smoke else args.steps

@@ -58,10 +58,7 @@ class TestTripCounts:
             return y
 
         compiled = jax.jit(f).lower(X, W).compile()
-        naive = compiled.cost_analysis()
-        if isinstance(naive, (list, tuple)):  # older jax wraps in a list
-            naive = naive[0] if naive else {}
-        naive = naive.get("flops", 0.0)
+        naive = compiled.cost_analysis().get("flops", 0.0)
         assert naive < 2 * MM_FLOPS  # counts ~1 matmul, not 16
         corrected = parse_hlo_costs(compiled.as_text())["flops"]
         assert corrected == pytest.approx(16 * MM_FLOPS, rel=0.01)
