@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-import repro.obs as _obs
 from repro.core.flexformat import quantize_em
 from repro.kernels.blockops import (
     block_max_exp,
@@ -587,13 +586,7 @@ def fused_sweep(
         out_shape=out_shape,
         interpret=interpret,
     )
-    with _obs.span("pallas.fused_sweep", steps=steps, grid=f"{gi}x{gj}"):
-        _obs.inc(
-            "repro_pallas_dispatch_total",
-            help="pallas_call dispatch sites entered",
-            kernel="fused_sweep",
-        )
-        outs = call(*inputs)
+    outs = call(*inputs)
 
     outs = list(outs)
     counts = None

@@ -49,8 +49,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-import repro.obs as _obs
-
 from repro.core.flexformat import quantize_em
 from repro.core.policy import RangeTracker, adjust_step
 from repro.kernels.fused import FusedOps, evidence_row, lane, resolve_interpret, row_of
@@ -420,13 +418,7 @@ def mega_sweep(
             interpret=interpret,
         )
     )
-    with _obs.span("pallas.mega_sweep", steps=steps, every=every):
-        _obs.inc(
-            "repro_pallas_dispatch_total",
-            help="pallas_call dispatch sites entered",
-            kernel="mega_sweep",
-        )
-        outs = list(call(*inputs))
+    outs = list(call(*inputs))
 
     # ---- unpack the flat output list -------------------------------------
     time_cnt = outs.pop() if (capture is not None and n_out > 0) else None

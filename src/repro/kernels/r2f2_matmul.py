@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-import repro.obs as _obs
 from repro.core.flexformat import quantize_em
 from repro.core.r2f2 import product_guard_bits, select_k
 from repro.kernels.blockops import block_max_exp
@@ -117,11 +116,5 @@ def r2f2_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=resolve_interpret(interpret),
     )
-    with _obs.span("pallas.r2f2_matmul", m=m, n=n, k=kdim):
-        _obs.inc(
-            "repro_pallas_dispatch_total",
-            help="pallas_call dispatch sites entered",
-            kernel="r2f2_matmul",
-        )
-        out = call(a, b)
+    out = call(a, b)
     return out[:m, :n] if (pm or pn) else out

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-import repro.obs as _obs
 from repro.core.flexformat import quantize_em
 from repro.core.r2f2 import select_k_operand
 from repro.kernels.blockops import block_max_exp
@@ -68,11 +67,5 @@ def r2f2_quantize_pallas(x, *, fmt, block=DEFAULT_BLOCK, interpret=None):
         ],
         interpret=resolve_interpret(interpret),
     )
-    with _obs.span("pallas.r2f2_quantize", m=m, n=n):
-        _obs.inc(
-            "repro_pallas_dispatch_total",
-            help="pallas_call dispatch sites entered",
-            kernel="r2f2_quantize",
-        )
-        y, k = call(x.astype(jnp.float32))
+    y, k = call(x.astype(jnp.float32))
     return y, k[:, :, 0, 0]

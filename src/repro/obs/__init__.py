@@ -33,9 +33,12 @@ materialises on the host and never feeds anything back.
 * Telemetry drains only *concrete* trackers: :func:`record_tracker`
   refuses jax tracers, so instrumented code inside ``jit``/``vmap``
   quietly skips the drain instead of corrupting the trace.
-* When observability is disabled (the default), every hook below is a
-  no-op measured in nanoseconds — :func:`span` returns a shared reentrant
-  null context manager and the counters short-circuit before any lookup.
+* :func:`span` and :func:`instant` always write to the profiler sink, a
+  ``jax.profiler.TraceAnnotation`` (about 1 us when no profiler is
+  running), so a ``jax.profiler`` trace carries them on the device
+  trace's clock with obs enabled or not. The in-memory :class:`Tracer`
+  records them only when observability is enabled; disabled (the
+  default), the counters short-circuit before any lookup.
 
 Usage::
 
@@ -61,7 +64,7 @@ from .metrics import (  # noqa: F401  (re-exported)
 )
 from .precision import PrecisionTelemetry, load_telemetry  # noqa: F401
 from .timing import Timing, measure  # noqa: F401
-from .trace import NULL_SPAN, Span, Tracer, load_trace  # noqa: F401
+from .trace import Span, Tracer, load_trace  # noqa: F401
 
 __all__ = [
     "Observability",
@@ -79,7 +82,6 @@ __all__ = [
     # re-exports
     "Tracer",
     "Span",
-    "NULL_SPAN",
     "load_trace",
     "MetricsRegistry",
     "Counter",
@@ -170,19 +172,70 @@ def enabled() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# instrumentation hooks — no-ops unless enable() was called
+# instrumentation hooks — the Tracer, registry and telemetry record only
+# after enable(); spans also reach a running jax.profiler trace
 # ---------------------------------------------------------------------------
 
+#: ``jax.profiler.TraceAnnotation``, imported on first use: the reporter
+#: (``python -m repro.obs``) reads artifacts without jax installed
+_annotation = None
+
+
+def _note(name: str, args: Dict[str, Any]):
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation as _annotation
+    return _annotation(name, **args)
+
+
+class _Span:
+    """A span on both sinks: a profiler annotation and a :class:`Tracer`
+    record. Like the annotation alone, it yields itself, and its
+    ``set_metadata`` attaches args known only inside the span."""
+
+    __slots__ = ("_note", "_rec", "_args")
+
+    def __init__(self, name: str, args: Dict[str, Any], tracer: Tracer):
+        self._note = _note(name, args)
+        self._rec = tracer.span(name, **args)
+        self._args: Optional[Dict[str, Any]] = None
+
+    def __enter__(self) -> "_Span":
+        self._note.__enter__()
+        self._args = self._rec.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._rec.__exit__(*exc)
+        self._note.__exit__(*exc)
+        return False
+
+    def set_metadata(self, **args) -> None:
+        self._note.set_metadata(**args)
+        self._args.update(args)
+
+
 def span(name: str, **args):
-    """A tracing span context manager (NULL_SPAN when disabled)."""
+    """A span context manager. It always enters a
+    ``jax.profiler.TraceAnnotation(name, **args)``, which a running
+    ``jax.profiler`` trace records on the device trace's clock; with
+    observability enabled the :class:`Tracer` records it too. The ``with``
+    target's ``set_metadata(**args)`` attaches late args to both.
+
+    Arg values reach the profiler as text or numbers; a text value must
+    hold no ``,`` or ``#``, which the profiler's metadata encoding uses.
+    """
     o = _OBS
     if o is None or o.tracer is None:
-        return NULL_SPAN
-    return o.tracer.span(name, **args)
+        return _note(name, args)
+    return _Span(name, args, o.tracer)
 
 
 def instant(name: str, **args) -> None:
-    """A zero-duration lifecycle event."""
+    """A zero-duration lifecycle event, on both sinks like :func:`span`."""
+    note = _note(name, args)
+    note.__enter__()
+    note.__exit__(None, None, None)
     o = _OBS
     if o is not None and o.tracer is not None:
         o.tracer.instant(name, **args)

@@ -4,8 +4,10 @@ A :class:`Tracer` records *complete* spans (``ph: "X"`` in Chrome-trace
 terms: a name, a start timestamp and a duration) and *instant* lifecycle
 events (``ph: "i"``), both carrying free-form JSON ``args``. Spans nest by
 plain dynamic scoping — a thread-local stack — so a served burst renders as
-a real timeline in Perfetto: ``service.pump`` containing ``service.chunk``
-containing the ``sim.run`` trace and its ``pallas.*`` dispatch spans.
+a real timeline in Perfetto: ``service.pump`` containing ``service.fill``,
+``service.stack``, ``service.chunk`` (and in it ``service.dispatch`` and
+``service.sync``) and ``service.unstack``. :func:`repro.obs.span` writes the
+same spans to the ``jax.profiler`` trace as well, on the device's clock.
 
 The recorder is deliberately dumb and host-only (DESIGN.md §15):
 
@@ -38,7 +40,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, NamedTuple, Optional
 
-__all__ = ["Span", "Tracer", "NULL_SPAN", "load_trace"]
+__all__ = ["Span", "Tracer", "load_trace"]
 
 
 class Span(NamedTuple):
@@ -61,19 +63,6 @@ class _Frame:
         self.keep = keep
         self.depth = depth
         self.t0 = t0
-
-
-class _NullSpan:
-    """Reentrant no-op context manager — the disabled-path span."""
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
 
 
 class Tracer:

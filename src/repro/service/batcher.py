@@ -131,15 +131,26 @@ class Bucket:
     def __len__(self) -> int:
         return len(self.members)
 
+    def member_ids(self) -> str:
+        """The members' request ids, space-separated (a span arg)."""
+        return " ".join(str(m.id) for m in self.members)
+
     def add(self, rec: RequestRecord) -> None:
         if rec.key != self.key:
             raise ValueError(
                 f"request {rec.id} (key {rec.key.short()}) is not compatible "
                 f"with bucket {self.key.short()}"
             )
-        self.members.append(rec)
-        rec.status = "running"
-        obs.instant("request.join", request=rec.id, bucket=self.key.short())
+        if rec.joined_at is None:  # the first join counts; a resume keeps it
+            rec.joined_at = time.perf_counter()
+        with obs.span(
+            "service.join",
+            request=rec.id,
+            bucket=self.key.short(),
+            wait_us=(rec.joined_at - rec.submitted_at) * 1e6,
+        ):
+            self.members.append(rec)
+            rec.status = "running"
 
     def next_chunk(self) -> int:
         """Steps until the earliest member event — the next chunk's length."""
@@ -165,13 +176,15 @@ class Bucket:
             sharded = mesh is not None
         chunk = self.next_chunk()
         n = len(self.members)
+        ids = self.member_ids()
         sim = self.members[0].sim  # identical (stepper, cfg, prec) by key
-
-        state_b = tree_stack([m.state for m in self.members])
         tracked = self.members[0].tracked
-        tracker_b = (
-            tree_stack([m.tracker for m in self.members]) if tracked else None
-        )
+
+        with obs.span("service.stack", members=ids):
+            state_b = tree_stack([m.state for m in self.members])
+            tracker_b = (
+                tree_stack([m.tracker for m in self.members]) if tracked else None
+            )
 
         fn, fresh = compiler.get(
             sim, self.key, chunk, n, sharded, mesh=mesh if sharded else None
@@ -179,23 +192,28 @@ class Bucket:
         with obs.span(
             "service.chunk",
             bucket=self.key.short(),
-            members=n,
+            members=ids,
             steps=chunk,
             compile=fresh,
         ):
             t0 = time.perf_counter()
-            out_state, out_snaps, out_tracker = jax.block_until_ready(
-                fn(state_b, tracker_b)
-            )
+            with obs.span("service.dispatch"):
+                out = fn(state_b, tracker_b)
+            with obs.span("service.sync"):
+                out_state, out_snaps, out_tracker = jax.block_until_ready(out)
             dt = time.perf_counter() - t0
         metrics.observe_chunk(self.key, n, chunk, dt, compiled=fresh)
         mon = health.active()
 
+        with obs.span("service.unstack", members=ids):
+            for i, m in enumerate(self.members):
+                m.state = tree_slice(out_state, i)
+                if tracked:
+                    m.tracker = tree_slice(out_tracker, i)
+
         drained: List[RequestRecord] = []
         for i, m in enumerate(self.members):
-            m.state = tree_slice(out_state, i)
             if tracked:
-                m.tracker = tree_slice(out_tracker, i)
                 obs.record_tracker(
                     f"req{m.id}:{m.key.stepper}", m.tracker, m.elapsed + chunk
                 )
@@ -204,12 +222,13 @@ class Bucket:
             m.elapsed += chunk
             m.chunks += 1
             if m.snapshot_due():
-                # snaps lead with (member, n_out=1, ...): this member's frame
-                snap = jax.tree_util.tree_map(
-                    lambda x: np.asarray(x[i, 0]), out_snaps
-                )
-                m.snapshots.append((m.elapsed, snap))
-                m.stream.emit("snapshot", m.elapsed, snap)
+                with obs.span("service.snapshot", request=m.id):
+                    # snaps lead with (member, n_out=1, ...): this member's frame
+                    snap = jax.tree_util.tree_map(
+                        lambda x: np.asarray(x[i, 0]), out_snaps
+                    )
+                    m.snapshots.append((m.elapsed, snap))
+                    m.stream.emit("snapshot", m.elapsed, snap)
                 metrics.snapshots_emitted += 1
                 if mon is not None:
                     mon.observe_frame(m, snap)
@@ -230,20 +249,26 @@ class Bucket:
 
     @staticmethod
     def _finalize(m: RequestRecord, metrics: ServiceMetrics) -> None:
-        final_k, adjustments = m.site_summary()
-        m.status = "done"
-        m.result = RequestResult(
-            state=jax.tree_util.tree_map(np.asarray, m.state),
-            snapshots=[a for _, a in m.snapshots],
-            snapshot_steps=[s for s, _ in m.snapshots],
-            tracker=m.tracker,
-            final_k=final_k,
-            adjustments=adjustments,
-            elapsed=m.elapsed,
+        m.done_at = time.perf_counter()
+        with obs.span(
+            "service.finalize",
+            request=m.id,
+            queue_us=(m.joined_at - m.submitted_at) * 1e6,
+            service_us=(m.done_at - m.joined_at) * 1e6,
+            steps=m.elapsed,
             chunks=m.chunks,
-        )
-        m.stream.emit("done", m.elapsed, m.result)
-        obs.instant(
-            "request.done", request=m.id, steps=m.elapsed, chunks=m.chunks
-        )
+        ):
+            final_k, adjustments = m.site_summary()
+            m.status = "done"
+            m.result = RequestResult(
+                state=jax.tree_util.tree_map(np.asarray, m.state),
+                snapshots=[a for _, a in m.snapshots],
+                snapshot_steps=[s for s, _ in m.snapshots],
+                tracker=m.tracker,
+                final_k=final_k,
+                adjustments=adjustments,
+                elapsed=m.elapsed,
+                chunks=m.chunks,
+            )
+            m.stream.emit("done", m.elapsed, m.result)
         metrics.observe_completion(adjustments)

@@ -30,6 +30,7 @@ signature additionally guards custom ``state0`` pytrees.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
@@ -159,6 +160,11 @@ class RequestRecord:
         self.error: Optional[str] = None  # set when status == "failed"
         self.ckpt_dir: Optional[str] = None
         self.templates = None  # ShapeDtypeStruct tree for ckpt restore
+        # time.perf_counter() when admission resolved the request, when it
+        # first joined a bucket (a resume keeps that) and when it finished
+        self.submitted_at: float = time.perf_counter()
+        self.joined_at: Optional[float] = None
+        self.done_at: Optional[float] = None
 
     # -- scheduling queries --------------------------------------------------
 

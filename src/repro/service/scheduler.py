@@ -35,7 +35,6 @@ can pump this loop from any thread.
 from __future__ import annotations
 
 import dataclasses
-import itertools
 import os
 from collections import deque
 from typing import Deque, Dict, List, Optional
@@ -94,7 +93,7 @@ class SimService:
         self._requests: Dict[int, RequestRecord] = {}
         self._terminal: Deque[int] = deque()  # retention FIFO of finished ids
         self._evicted: Deque[RequestRecord] = deque()
-        self._ids = itertools.count(1)
+        self._next_id = 1
         self._compiler = ChunkCompiler()
         self._rr = 0  # round-robin bucket cursor
 
@@ -103,31 +102,28 @@ class SimService:
     def submit(self, req: SimRequest) -> RequestHandle:
         """Admit one request (resolved eagerly; may raise, see
         ``resolve_request``) or raise :class:`ServiceOverloaded`."""
-        if len(self._queue) >= self.config.max_queue:
-            self.metrics.rejected += 1
-            raise ServiceOverloaded(
-                f"admission queue is full ({self.config.max_queue} requests); "
-                "pump the service or retry later"
-            )
-        try:
-            rec = resolve_request(next(self._ids), req)
-        except Exception:
-            self.metrics.rejected += 1
-            raise
-        self._queue.append(rec)
-        self._requests[rec.id] = rec
-        self.metrics.submitted += 1
-        mon = health.active()
-        if mon is not None:
-            mon.on_submit(rec)  # deterministic shadow-sampling decision
-        obs.instant(
-            "request.submit",
-            request=rec.id,
-            stepper=rec.key.stepper,
-            mode=rec.key.prec.mode,
-            steps=rec.steps,
-        )
-        return RequestHandle(rec)
+        rid = self._next_id
+        with obs.span("service.submit", request=rid):
+            if len(self._queue) >= self.config.max_queue:
+                self.metrics.rejected += 1
+                raise ServiceOverloaded(
+                    f"admission queue is full ({self.config.max_queue} requests); "
+                    "pump the service or retry later"
+                )
+            self._next_id += 1
+            try:
+                with obs.span("service.resolve", request=rid):
+                    rec = resolve_request(rid, req)
+            except Exception:
+                self.metrics.rejected += 1
+                raise
+            self._queue.append(rec)
+            self._requests[rec.id] = rec
+            self.metrics.submitted += 1
+            mon = health.active()
+            if mon is not None:
+                mon.on_submit(rec)  # deterministic shadow-sampling decision
+            return RequestHandle(rec)
 
     def handle(self, request_id: int) -> RequestHandle:
         return RequestHandle(self._requests[request_id])
@@ -143,9 +139,7 @@ class SimService:
                 return False
             bucket = buckets[self._rr % len(buckets)]
             self._rr += 1
-            if sp is not None:
-                sp["bucket"] = bucket.key.short()
-                sp["members"] = len(bucket)
+            sp.set_metadata(bucket=bucket.key.short(), members=bucket.member_ids())
             mon = health.active()
             if mon is not None:
                 mon.note_occupancy(self.queued, self.active_members)
@@ -221,28 +215,29 @@ class SimService:
         return b
 
     def _fill(self) -> None:
-        cfg = self.config
-        while self._queue and self.active_members < cfg.max_active_members:
-            rec = self._queue.popleft()
-            self._bucket_for(rec).add(rec)
-        # pressure: spill the longest-remaining member to admit queued work
-        while self._queue and cfg.auto_evict:
-            victim = self._evictable()
-            if victim is None or victim.remaining <= self._queue[0].remaining:
-                break
-            self.evict(victim.id)
-            rec = self._queue.popleft()
-            self._bucket_for(rec).add(rec)
-        # free slots + no fresh work: transparently restore evicted members
-        while (
-            cfg.auto_resume
-            and self._evicted
-            and not self._queue
-            and self.active_members < cfg.max_active_members
-        ):
-            self.resume(self._evicted[0].id)
-            rec = self._queue.popleft()  # resume() re-queues; admit it now
-            self._bucket_for(rec).add(rec)
+        with obs.span("service.fill"):
+            cfg = self.config
+            while self._queue and self.active_members < cfg.max_active_members:
+                rec = self._queue.popleft()
+                self._bucket_for(rec).add(rec)
+            # pressure: spill the longest-remaining member to admit queued work
+            while self._queue and cfg.auto_evict:
+                victim = self._evictable()
+                if victim is None or victim.remaining <= self._queue[0].remaining:
+                    break
+                self.evict(victim.id)
+                rec = self._queue.popleft()
+                self._bucket_for(rec).add(rec)
+            # free slots + no fresh work: transparently restore evicted members
+            while (
+                cfg.auto_resume
+                and self._evicted
+                and not self._queue
+                and self.active_members < cfg.max_active_members
+            ):
+                self.resume(self._evicted[0].id)
+                rec = self._queue.popleft()  # resume() re-queues; admit it now
+                self._bucket_for(rec).add(rec)
 
     def _evictable(self) -> Optional[RequestRecord]:
         members = [m for b in self._live_buckets() for m in b.members]

@@ -133,6 +133,18 @@ class RequestHandle:
     def done(self) -> bool:
         return self._record.status in ("done", "failed")
 
+    @property
+    def queue_s(self) -> Optional[float]:
+        """Seconds from admission to the first bucket join (None before)."""
+        r = self._record
+        return None if r.joined_at is None else r.joined_at - r.submitted_at
+
+    @property
+    def service_s(self) -> Optional[float]:
+        """Seconds from the first bucket join to done (None before)."""
+        r = self._record
+        return None if r.done_at is None else r.done_at - r.joined_at
+
     def result(self):
         """The final :class:`RequestResult`; raises unless ``status=='done'``."""
         if self._record.status == "failed":

@@ -12,8 +12,12 @@ tier's property pass stays inside its time budget; set it higher locally
 for a deeper sweep.
 """
 
+import glob
 import os
 import sys
+from typing import Any, Dict, NamedTuple
+
+import pytest
 
 collect_ignore = []
 
@@ -33,3 +37,39 @@ except ImportError:
     _stub = importlib.util.module_from_spec(_spec)
     sys.modules["hypothesis"] = _stub
     _spec.loader.exec_module(_stub)
+
+
+class HostSpan(NamedTuple):
+    name: str
+    start_ns: float
+    end_ns: float
+    stats: Dict[str, Any]
+
+
+@pytest.fixture
+def profiled(tmp_path):
+    """``profiled(body)`` runs ``body()`` under a ``jax.profiler`` trace and
+    returns ``(body's result, host spans)``: the events of the trace's
+    ``/host:CPU`` plane as :class:`HostSpan`, in start order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    def run(body):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            out = body()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        spans = [
+            HostSpan(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines
+            for ev in line.events
+        ]
+        return out, sorted(spans, key=lambda s: s.start_ns)
+
+    return run

@@ -59,6 +59,41 @@ def assert_bits_equal(a, b):
 # ---------------------------------------------------------------------------
 
 
+class TestProfilerSink:
+    """``obs.span`` / ``obs.instant`` always reach a running jax.profiler
+    trace, on its host plane with their args as stats; the Tracer records
+    them only when obs is enabled."""
+
+    @pytest.mark.parametrize("enabled", [False, True], ids=["obs_off", "obs_on"])
+    def test_spans_land_on_host_plane_with_args_and_nest(self, profiled, enabled):
+        if enabled:
+            obs.enable()
+
+        def body():
+            with obs.span("unit.outer", request=7, members="3 4") as sp:
+                with obs.span("unit.inner", wait_us=12.5):
+                    pass
+                sp.set_metadata(late=2)
+            obs.instant("unit.mark", request=7)
+
+        _, spans = profiled(body)
+        by = {s.name: s for s in spans if s.name.startswith("unit.")}
+        assert set(by) == {"unit.outer", "unit.inner", "unit.mark"}
+        outer, inner, mark = by["unit.outer"], by["unit.inner"], by["unit.mark"]
+        assert outer.stats == {"request": 7, "members": "3 4", "late": 2}
+        assert inner.stats == {"wait_us": 12.5}
+        assert mark.stats == {"request": 7}
+        assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+        assert outer.end_ns <= mark.start_ns
+        if enabled:
+            recorded = {s.name: s for s in obs.active().tracer.spans}
+            assert set(recorded) == set(by)
+            assert recorded["unit.outer"].args == {"request": 7, "members": "3 4", "late": 2}
+            assert recorded["unit.inner"].depth == 1
+            assert recorded["unit.mark"].dur_us is None
+
+
+
 class TestTracer:
     def test_span_nesting_depths(self):
         tr = Tracer()
