@@ -889,10 +889,10 @@ class Simulation:
         (a SiteTracker whose state arrays lead with the member dim — e.g.
         the ``tracker`` a previous ``run_ensemble`` returned). This is the
         repacking contract ``repro.service`` builds its continuous batching
-        on: between chunks the serving plane drains finished members, adds
-        joiners, restacks ``(state, tracker)`` and calls back in — each
-        member's carried split ``k`` and §5.3 adjustment counters survive
-        the repack because they are handed straight back here.
+        on: the serving plane hands a chunk's returned ``(state, tracker)``
+        straight back in, restacked only when members drained or joined —
+        each member's carried split ``k`` and §5.3 adjustment counters
+        survive the repack because they are handed straight back here.
 
         ``storage`` behaves as in :meth:`run`, per member; a packed
         ensemble's state batch (initial and returned) is a PackedArray tree

@@ -11,13 +11,23 @@ Pallas kernel chunks; the bucket exploits exactly that seam:
   a point where a solo run would have paused. Members with heterogeneous
   cadences/horizons coexist; the bucket just pauses more often.
 * **join/drain between chunks** — the member list is plain host state
-  between chunks: finished requests drain out, queued compatible requests
-  pack in, and the next chunk call restacks ``(state, tracker)``. Because
-  each member's carried :class:`SiteTracker` rows (split ``k``, EMAs, §5.3
-  adjustment counters) ride the stack and come back sliced, repacking is
-  *semantically invisible* — a member's trajectory is bit-identical to its
-  solo ``Simulation.run`` (asserted per stepper/mode in
-  ``tests/test_service.py``).
+  between chunks: finished requests drain out and queued compatible
+  requests pack in. Because each member's carried :class:`SiteTracker`
+  rows (split ``k``, EMAs, §5.3 adjustment counters) ride the stack with
+  its state, repacking is *semantically invisible* — a member's trajectory
+  is bit-identical to its solo ``Simulation.run`` (asserted per
+  stepper/mode in ``tests/test_service.py``).
+* **the resident batch** — the bucket keeps the chunk program's stacked
+  outputs ``(state, tracker)`` on the device and hands them straight to
+  the next chunk; members hold no copy of their own while they run. The
+  batch is rebuilt (one host round trip: fetch, compose in NumPy, one
+  ``device_put``) only when the membership changed since the last chunk
+  or there is no batch yet, and a member is materialised on the host (its
+  row of one fetch of the batch) only when it leaves — drain, eviction or
+  failure, all through :meth:`Bucket.remove` — or when a consumer reads
+  ``RequestRecord.state``/``.tracker`` while it runs. Snapshots come to
+  the host in one transfer per chunk. The batch's width is the member
+  count either way, so the compiled chunk program is the same one.
 * **compiled-chunk cache** — chunk programs are jitted once per
   ``(bucket key, chunk steps, member count)`` and reused across repacks, so
   steady-state traffic pays tracing cost only when the packing shape
@@ -43,27 +53,27 @@ from collections import OrderedDict
 from typing import Callable, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 import repro.obs as obs
 import repro.obs.health as health
-from repro.dist.sharding import active_mesh
+from repro.dist.sharding import active_mesh, member_spec
 
 from .metrics import ServiceMetrics
 from .request import BucketKey, RequestRecord, RequestResult
 
-__all__ = ["Bucket", "ChunkCompiler", "tree_stack", "tree_slice"]
+__all__ = ["Bucket", "ChunkCompiler"]
 
 
-def tree_stack(trees):
-    """Stack a list of congruent pytrees along a new leading member dim."""
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+def _stack(trees):
+    """Stack a list of congruent host pytrees along a new leading member dim."""
+    return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *trees)
 
 
-def tree_slice(tree, i: int):
-    """Member ``i``'s slice of a stacked pytree (drops the member dim)."""
-    return jax.tree_util.tree_map(lambda x: x[i], tree)
+def _row(tree, i: int):
+    """Member ``i``'s row of a stacked host pytree, as arrays of its own."""
+    return jax.tree_util.tree_map(lambda x: np.array(x[i]), tree)
 
 
 class ChunkCompiler:
@@ -122,11 +132,25 @@ class ChunkCompiler:
 
 
 class Bucket:
-    """One packing of compatible requests; advances one chunk at a time."""
+    """One packing of compatible requests; advances one chunk at a time.
+
+    ``members`` is the current packing. The resident batch ``_batch`` is
+    the last chunk's stacked ``(state, tracker)`` on the device, its rows in
+    the order of ``_rows`` (the members of that chunk); ``_change`` names
+    the first membership change since that chunk (None: the batch is
+    current). Members join with :meth:`add` and leave only through
+    :meth:`remove`.
+    """
 
     def __init__(self, key: BucketKey):
         self.key = key
         self.members: List[RequestRecord] = []
+        self._batch = None
+        self._rows: List[RequestRecord] = []
+        #: host copies of the batch's state and tracker, each fetched at most
+        #: once per chunk (None: not fetched since the batch last changed)
+        self._host: List = [None, None]
+        self._change: Optional[str] = None
 
     def __len__(self) -> int:
         return len(self.members)
@@ -151,6 +175,59 @@ class Bucket:
         ):
             self.members.append(rec)
             rec.status = "running"
+            self._change = self._change or "join"
+
+    def remove(self, leaving: List[RequestRecord], reason: str) -> None:
+        """The only way members leave (``reason``: ``drain``, ``evict`` or
+        ``failure``): each takes its ``(state, tracker)`` row from one host
+        copy of the resident batch, then drops out; the next chunk rebuilds
+        the batch."""
+        resident = [m for m in leaving if m.resident_in is self]
+        if resident:
+            with obs.span(
+                "service.unstack",
+                members=" ".join(str(m.id) for m in resident),
+                reason=reason,
+            ):
+                for m in resident:
+                    m.state, m.tracker = self.row(m, 0, 1)
+                    m.resident_in = None
+        for m in leaving:
+            self.members.remove(m)
+        self._change = self._change or reason
+        if not self.members:  # nothing left to run: free the device copy
+            self._batch, self._rows, self._host = None, [], [None, None]
+
+    def row(self, rec: RequestRecord, *parts: int) -> Tuple:
+        """A resident member's rows of the batch's ``parts`` (0: state, 1:
+        tracker), copied out of their host copies. Each part is fetched at
+        most once per chunk, all missing ones in one transfer."""
+        missing = [p for p in parts if self._host[p] is None]
+        for p, x in zip(missing, jax.device_get([self._batch[p] for p in missing])):
+            self._host[p] = x
+        i = self._rows.index(rec)
+        return tuple(_row(self._host[p], i) for p in parts)
+
+    def _restack(self, tracked: bool, sharded: bool) -> None:
+        """Rebuild the resident batch for the current members: the rows of
+        those already in it and the own copies of joiners, composed on the
+        host and put on the device in one transfer. A sharded batch goes
+        straight onto the member sharding the chunk program returns, so
+        restacked and resident batches reach the same executable."""
+        joiners = [m for m in self.members if m.resident_in is not self]
+        own = dict(zip(joiners, jax.device_get([(m.state, m.tracker) for m in joiners])))
+        rows = [own[m] if m in own else self.row(m, 0, 1) for m in self.members]
+        state_b = _stack([st for st, _ in rows])
+        tracker_b = _stack([tr for _, tr in rows]) if tracked else None
+        spec = member_spec(len(self.members)) if sharded else None
+        self._batch = jax.device_put(
+            (state_b, tracker_b), None if spec is None else NamedSharding(*spec)
+        )
+        self._host = [state_b, tracker_b]
+        self._rows = list(self.members)
+        for m in self.members:
+            m.state = m.tracker = None
+            m.resident_in = self
 
     def next_chunk(self) -> int:
         """Steps until the earliest member event — the next chunk's length."""
@@ -180,11 +257,11 @@ class Bucket:
         sim = self.members[0].sim  # identical (stepper, cfg, prec) by key
         tracked = self.members[0].tracked
 
-        with obs.span("service.stack", members=ids):
-            state_b = tree_stack([m.state for m in self.members])
-            tracker_b = (
-                tree_stack([m.tracker for m in self.members]) if tracked else None
-            )
+        reason = "first" if self._batch is None else self._change
+        if reason is not None:
+            with obs.span("service.stack", members=ids, reason=reason):
+                self._restack(tracked, sharded)
+        self._change = None
 
         fn, fresh = compiler.get(
             sim, self.key, chunk, n, sharded, mesh=mesh if sharded else None
@@ -198,22 +275,28 @@ class Bucket:
         ):
             t0 = time.perf_counter()
             with obs.span("service.dispatch"):
-                out = fn(state_b, tracker_b)
+                out = fn(*self._batch)
             with obs.span("service.sync"):
                 out_state, out_snaps, out_tracker = jax.block_until_ready(out)
             dt = time.perf_counter() - t0
+        self._batch, self._host = (out_state, out_tracker), [None, None]
         metrics.observe_chunk(self.key, n, chunk, dt, compiled=fresh)
+        if reason is None:
+            metrics.resident_chunks += 1
+        else:
+            metrics.restacks += 1
         mon = health.active()
-
-        with obs.span("service.unstack", members=ids):
-            for i, m in enumerate(self.members):
-                m.state = tree_slice(out_state, i)
-                if tracked:
-                    m.tracker = tree_slice(out_tracker, i)
+        o = obs.active()
+        # per-chunk tracker consumers read the members' rows of one host
+        # copy of the stacked tracker
+        watched = tracked and (
+            mon is not None or (o is not None and o.telemetry is not None)
+        )
 
         drained: List[RequestRecord] = []
+        snaps = None  # one host copy of the chunk's frames, once one is due
         for i, m in enumerate(self.members):
-            if tracked:
+            if watched:
                 obs.record_tracker(
                     f"req{m.id}:{m.key.stepper}", m.tracker, m.elapsed + chunk
                 )
@@ -223,10 +306,10 @@ class Bucket:
             m.chunks += 1
             if m.snapshot_due():
                 with obs.span("service.snapshot", request=m.id):
+                    if snaps is None:
+                        snaps = jax.device_get(out_snaps)
                     # snaps lead with (member, n_out=1, ...): this member's frame
-                    snap = jax.tree_util.tree_map(
-                        lambda x: np.asarray(x[i, 0]), out_snaps
-                    )
+                    snap = jax.tree_util.tree_map(lambda x: np.array(x[i, 0]), snaps)
                     m.snapshots.append((m.elapsed, snap))
                     m.stream.emit("snapshot", m.elapsed, snap)
                 metrics.snapshots_emitted += 1
@@ -240,8 +323,9 @@ class Bucket:
         if mon is not None:
             mon.on_chunk(self.key, n, chunk, dt, compiled=fresh)
 
+        if drained:
+            self.remove(drained, "drain")
         for m in drained:
-            self.members.remove(m)
             self._finalize(m, metrics)
             if mon is not None:
                 mon.on_request_done(m)
@@ -261,7 +345,7 @@ class Bucket:
             final_k, adjustments = m.site_summary()
             m.status = "done"
             m.result = RequestResult(
-                state=jax.tree_util.tree_map(np.asarray, m.state),
+                state=m.state,
                 snapshots=[a for _, a in m.snapshots],
                 snapshot_steps=[s for s, _ in m.snapshots],
                 tracker=m.tracker,

@@ -17,7 +17,10 @@ accumulates everything the ISSUE's production story needs to be judged by:
   completed tracked requests, aggregated by site name: the fleet-level view
   of how hard the precision-adjust unit worked;
 * lifecycle counters — submitted / rejected (backpressure) / completed /
-  evicted / resumed / snapshots streamed.
+  evicted / resumed / snapshots streamed;
+* **resident batch** — chunk calls whose bucket batch was rebuilt because
+  its membership changed (``restacks``) against those that reused the
+  previous chunk's outputs on the device (``resident_chunks``).
 
 Since PR 9 this class is a thin consumer of a
 :class:`repro.obs.MetricsRegistry` — every counter/histogram lives in the
@@ -59,6 +62,10 @@ _LIFECYCLE = {
                      "member-steps advanced (all chunk calls)"),
     "compiles": ("repro_service_compiles_total",
                  "chunk calls that traced+compiled a fresh program"),
+    "restacks": ("repro_service_restacks_total",
+                 "chunk calls whose bucket batch was rebuilt (membership changed)"),
+    "resident_chunks": ("repro_service_resident_chunks_total",
+                        "chunk calls that reused the bucket's resident batch"),
 }
 
 _FLOAT_COUNTERS = {
@@ -219,6 +226,8 @@ class ServiceMetrics:
             "busy_seconds": self.busy_seconds,
             "compiles": self.compiles,
             "compile_seconds": self.compile_seconds,
+            "restacks": self.restacks,
+            "resident_chunks": self.resident_chunks,
             "throughput_steps_per_s": self.throughput(),
             "chunk_latency_p50_us": self.latency_us(50),
             "chunk_latency_p99_us": self.latency_us(99),
@@ -243,7 +252,8 @@ class ServiceMetrics:
             f"  throughput  {s['throughput_steps_per_s']:.0f} member-steps/s "
             f"({s['member_steps']} steps, {s['snapshots_emitted']} snapshots streamed)",
             f"  occupancy   mean={s['occupancy_mean']:.2f} max={s['occupancy_max']} "
-            f"members/chunk",
+            f"members/chunk (restacks={s['restacks']} "
+            f"resident_chunks={s['resident_chunks']})",
         ]
         if s["site_adjustments"]:
             adj = ", ".join(

@@ -132,9 +132,13 @@ class RequestRecord:
     """The live, mutable runtime record of one admitted request.
 
     ``state``/``tracker`` are the member's carried simulation state between
-    chunks — the batcher stacks them into a bucket's vmapped call and hands
-    the sliced results back, so the adjust unit's ``k`` and §5.3 counters
-    genuinely survive repacking, eviction and resume.
+    chunks. While the member runs, they live as its row of its bucket's
+    resident batch (``resident_in``) and a read returns that row from the
+    host copy of the batch, fetched at most once per chunk; otherwise
+    (queued, evicted, finished) the record holds its own copy. The batcher stacks a joining member's
+    copy into the batch and materialises it again when the member leaves,
+    so the adjust unit's ``k`` and §5.3 counters genuinely survive
+    repacking, eviction and resume.
 
     Lifecycle (``status``): ``queued`` -> ``running`` -> (``evicted`` <->
     ``running``) -> ``done`` | ``failed``.
@@ -146,6 +150,9 @@ class RequestRecord:
         self.req = req
         self.sim = sim
         self.key = key
+        #: the Bucket whose resident batch holds this member's carried
+        #: (state, tracker), or None while the record holds its own copy
+        self.resident_in = None
         self.state = state
         self.tracker = tracker
         self.tracked = tracker is not None
@@ -165,6 +172,28 @@ class RequestRecord:
         self.submitted_at: float = time.perf_counter()
         self.joined_at: Optional[float] = None
         self.done_at: Optional[float] = None
+
+    # -- carried state -------------------------------------------------------
+
+    @property
+    def state(self):
+        if self.resident_in is not None:
+            return self.resident_in.row(self, 0)[0]
+        return self._state
+
+    @state.setter
+    def state(self, value):
+        self._state = value
+
+    @property
+    def tracker(self):
+        if self.resident_in is not None:
+            return self.resident_in.row(self, 1)[0]
+        return self._tracker
+
+    @tracker.setter
+    def tracker(self, value):
+        self._tracker = value
 
     # -- scheduling queries --------------------------------------------------
 

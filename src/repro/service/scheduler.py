@@ -148,8 +148,9 @@ class SimService:
                     self._compiler, self.metrics, sharded=self.config.sharded
                 )
             except Exception as e:  # compile/runtime failure: fail the members
-                for m in list(bucket.members):
-                    bucket.members.remove(m)
+                failed = list(bucket.members)
+                bucket.remove(failed, "failure")
+                for m in failed:
                     m.status = "failed"
                     m.error = repr(e)
                     m.stream.emit("failed", m.elapsed, repr(e))
@@ -252,10 +253,10 @@ class SimService:
     def evict(self, request_id: int) -> str:
         """Checkpoint a running (or still-queued) request out of the service.
 
-        The member's carried ``(state, tracker)`` goes through
-        ``repro.ckpt`` (atomic directory rename; f32/int32 arrays round-trip
-        bit-exactly) stamped with its elapsed step; the slot frees
-        immediately. Returns the checkpoint directory."""
+        The member's carried ``(state, tracker)`` as of its last chunk
+        goes through ``repro.ckpt`` (atomic directory rename; f32/int32
+        arrays round-trip bit-exactly) stamped with its elapsed step; the
+        slot frees immediately. Returns the checkpoint directory."""
         rec = self._requests[request_id]
         if rec.status not in ("running", "queued"):
             raise ValueError(
@@ -272,7 +273,7 @@ class SimService:
         if rec.status == "running":
             for b in self._buckets.get(rec.key, []):
                 if rec in b.members:
-                    b.members.remove(rec)
+                    b.remove([rec], "evict")
                     break
             self._gc_buckets()
         else:

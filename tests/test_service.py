@@ -13,6 +13,7 @@ streaming, metrics, and the solver's new ``tracker0_batch`` repacking entry.
 """
 
 import dataclasses
+import functools
 import os
 
 import jax
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.obs as obs
 from repro.core.flexformat import FlexFormat
 from repro.core.policy import PRESETS, PrecisionConfig
 from repro.pde import (
@@ -68,6 +70,15 @@ def _scaled(state, s):
     return jax.tree_util.tree_map(lambda x: (s * x).astype(x.dtype), state)
 
 
+@functools.lru_cache(maxsize=None)
+def _solo(stepper, mode, steps, every, scale=None):
+    """The solo reference run of a request (shared by the tests that serve it)."""
+    cfg = SMALL_CFGS[stepper]
+    sim = Simulation(stepper, cfg, dict((m[0], m[1]) for m in MODES)[mode])
+    state0 = None if scale is None else _scaled(sim.stepper.init_state(cfg), scale)
+    return sim.run(steps, snapshot_every=every, state0=state0)
+
+
 def _assert_trackers_equal(a, b):
     assert (a is None) == (b is None)
     if a is None:
@@ -114,8 +125,8 @@ class TestPackingInvisibility:
         # they really shared one bucket (continuous batching, not siblings)
         assert svc.metrics.occupancy()[1] == 2
 
-        soloA = sim.run(24, snapshot_every=8)
-        soloB = sim.run(18, snapshot_every=6, state0=s0b)
+        soloA = _solo(stepper, mode, 24, 8)
+        soloB = _solo(stepper, mode, 18, 6, 0.5)
         for h, solo in ((hA, soloA), (hB, soloB)):
             if bit_exact:
                 np.testing.assert_array_equal(
@@ -358,6 +369,161 @@ class TestEvictionResume:
         np.testing.assert_array_equal(
             np.stack(hLong.snapshots), np.asarray(soloL.snapshots)
         )
+
+
+# ---------------------------------------------------------------------------
+# the resident batch: restacked only on a membership change
+# ---------------------------------------------------------------------------
+
+
+def _fail_second_chunk(svc, key):
+    """Make the chunk program of bucket key ``key`` raise on its second call."""
+    get, calls = svc._compiler.get, []
+
+    def wrapped(sim, k, *args, **kw):
+        fn, fresh = get(sim, k, *args, **kw)
+        if k != key:
+            return fn, fresh
+
+        def chunk(*xs):
+            calls.append(k)
+            if len(calls) == 2:
+                raise RuntimeError("injected chunk failure")
+            return fn(*xs)
+
+        return chunk, fresh
+
+    svc._compiler.get = wrapped
+
+
+def _assert_tree_equal(a, b):
+    jax.tree_util.tree_map(
+        lambda x, y: np.testing.assert_array_equal(np.asarray(x), np.asarray(y)), a, b
+    )
+
+
+class TestResidentBatch:
+    @pytest.mark.parametrize("stepper", sorted(SMALL_CFGS))
+    @pytest.mark.parametrize("mode", [m[0] for m in MODES])
+    def test_schedule_bit_identical_to_solo(self, stepper, mode, tmp_path):
+        """Members joining and draining mid-run, one evicted and resumed, and
+        a sibling bucket whose second chunk raises: every member that
+        finishes matches its solo run, and the failed one leaves with its
+        state as of its last good chunk."""
+        prec = dict((m[0], m[1]) for m in MODES)[mode]
+        other = "bf16" if mode == "f32" else "f32"
+        cfg = SMALL_CFGS[stepper]
+        init = Simulation(stepper, cfg, prec).stepper.init_state(cfg)
+
+        def req(steps, every, scale=None, precision=prec):
+            return SimRequest(stepper, steps=steps, precision=precision, cfg=cfg,
+                              snapshot_every=every,
+                              state0=None if scale is None else _scaled(init, scale),
+                              execution="reference")
+
+        svc = SimService(ServiceConfig(ckpt_dir=str(tmp_path), auto_resume=False))
+        hA = svc.submit(req(24, 8))
+        hB = svc.submit(req(18, 6, 0.5))
+        svc.pump()  # A and B at 6
+        hC = svc.submit(req(12, 4, 1.5))
+        svc.pump()  # C joins: all advance 2
+        svc.evict(hB.id)  # B leaves mid-run at 8 (not one of its events)
+        hD = svc.submit(req(12, 4, precision=PRESETS[other]))  # a sibling bucket
+        _fail_second_chunk(svc, hD._record.key)
+        svc.pump()
+        svc.pump()
+        svc.resume(hB.id)
+        failures = 0
+        for _ in range(100):
+            try:
+                if not svc.pump():
+                    break
+            except RuntimeError:
+                failures += 1
+        assert failures == 1
+
+        for h, run in ((hA, (24, 8)), (hB, (18, 6, 0.5)), (hC, (12, 4, 1.5))):
+            assert h.status == "done"
+            solo = _solo(stepper, mode, *run)
+            np.testing.assert_array_equal(np.stack(h.snapshots), np.asarray(solo.snapshots))
+            _assert_tree_equal(h.result().state, solo.state)
+            _assert_trackers_equal(h.result().tracker, solo.tracker)
+        assert svc.metrics.evicted == 1 and svc.metrics.resumed == 1
+
+        recD = hD._record
+        assert hD.status == "failed" and recD.elapsed == 4
+        _assert_tree_equal(recD.state, _solo(stepper, other, 4, 4).state)
+        assert recD.resident_in is None
+        assert svc.active_members == 0
+
+    @pytest.mark.parametrize("mode", [m[0] for m in MODES])
+    def test_lone_request_restacks_once(self, mode):
+        """A request alone in its bucket for 8 chunks builds its batch once
+        and reuses it for the other 7."""
+        prec = dict((m[0], m[1]) for m in MODES)[mode]
+        svc = SimService(ServiceConfig())
+        h = svc.submit(SimRequest("heat1d", steps=16, precision=prec,
+                                  cfg=HeatConfig(nx=48), snapshot_every=2))
+        svc.run_until_idle()
+        assert h.status == "done" and h.result().chunks == 8
+        m = svc.metrics
+        assert (m.restacks, m.resident_chunks) == (1, 7)
+        assert m.registry.counter("repro_service_restacks_total").total() == 1
+        assert m.registry.counter("repro_service_resident_chunks_total").total() == 7
+
+    @pytest.mark.parametrize("stepper", sorted(SMALL_CFGS))
+    def test_tracker_telemetry_matches_chunk_outputs(self, stepper):
+        """With telemetry on, each request's per-chunk tracker series is its
+        row of every chunk's stacked output tracker, entry for entry."""
+        cfg = SMALL_CFGS[stepper]
+        s0b = _scaled(Simulation(stepper, cfg, TRACKED).stepper.init_state(cfg), 0.5)
+        svc = SimService(ServiceConfig())
+        rows = {}  # request id -> [(step, k, grew, shrank)] sliced from the outputs
+        get = svc._compiler.get
+
+        def spy(sim, key, chunk, *args, **kw):
+            fn, fresh = get(sim, key, chunk, *args, **kw)
+
+            def run(*xs):
+                out = fn(*xs)
+                (bucket,) = svc._live_buckets()
+                st = out[2].state
+                for i, m in enumerate(bucket.members):
+                    rows.setdefault(m.id, []).append((
+                        m.elapsed + chunk, np.asarray(st.k[i]),
+                        np.asarray(st.overflow_steps[i]), np.asarray(st.shrink_steps[i]),
+                    ))
+                return out
+
+            return run, fresh
+
+        svc._compiler.get = spy
+        obs.enable()
+        try:
+            hA = svc.submit(SimRequest(stepper, steps=24, precision=TRACKED, cfg=cfg,
+                                       snapshot_every=8, execution="reference"))
+            svc.pump()
+            hB = svc.submit(SimRequest(stepper, steps=18, precision=TRACKED, cfg=cfg,
+                                       snapshot_every=6, state0=s0b,
+                                       execution="reference"))
+            svc.run_until_idle()
+            tel = obs.active().telemetry
+            names = hA.result().tracker.names
+            series = {
+                h.id: [tel.series(f"req{h.id}:{stepper}", n) for n in names]
+                for h in (hA, hB)
+            }
+        finally:
+            obs.disable()
+
+        for h in (hA, hB):
+            expected = rows[h.id]
+            assert len(expected) == h.result().chunks
+            for i, s in enumerate(series[h.id]):
+                assert s.steps == [step for step, *_ in expected]
+                assert s.k == [int(k[i]) for _, k, _, _ in expected]
+                assert s.grew == [int(g[i]) for _, _, g, _ in expected]
+                assert s.shrank == [int(r[i]) for _, _, _, r in expected]
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,9 @@
 Under a CPU profiler trace, a ``SimService`` serving three heat1d requests
 writes one span per boundary of its host path, nested under
 ``service.pump`` or ``service.submit``, each carrying the request ids it
-handles; its results are bit-identical with the profiler on and off."""
+handles: ``service.stack`` once per membership change of the bucket, with
+its reason, and ``service.unstack`` once per departure. Its results are
+bit-identical with the profiler on and off."""
 
 import numpy as np
 import pytest
@@ -71,6 +73,18 @@ def test_served_path_spans(profiled):
         members = [int(m) for m in str(s.stats["members"]).split()]
         assert set(members) <= set(ids)
         assert s.stats["steps"] > 0
+
+    # A and B start together, C joins after one chunk, A and B drain after
+    # the second and C after the third: three packings, three chunks
+    def members(s):
+        return [int(m) for m in str(s.stats["members"]).split()]
+
+    a, b, c = ids
+    assert [(s.stats["reason"], members(s)) for s in by["service.stack"]] == [
+        ("first", [a, b]), ("join", [a, b, c]), ("drain", [c])]
+    assert [(s.stats["reason"], members(s)) for s in by["service.unstack"]] == [
+        ("drain", [a, b]), ("drain", [c])]
+    assert len(by["service.chunk"]) == 3
 
     assert all(s.stats["wait_us"] >= 0 for s in by["service.join"])
     for h in handles:
