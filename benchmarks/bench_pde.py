@@ -72,7 +72,7 @@ class Scenario:
 def scenarios():
     """Scenario table, keyed by stepper name (configs/* are the source of
     figure-faithful shapes/steps)."""
-    from repro.configs import advection1d, burgers1d, heat1d, heat2d, swe2d
+    from repro.configs import advection1d, burgers1d, heat1d, heat2d, swe2d, williamson5
 
     return {
         "heat1d": Scenario(heat1d.CONFIG, heat1d.BENCH_STEPS["sin"]),
@@ -85,6 +85,12 @@ def scenarios():
             precs=("e5m10", "r2f2_16", "r2f2_16_384", "bf16", "rr_tracked"),
             judge="corr",
             offset=swe2d.CONFIG.depth,
+        ),
+        "swe_sphere": Scenario(
+            williamson5.CONFIG,
+            williamson5.BENCH_STEPS,
+            precs=("e5m10", "r2f2_16", "r2f2_16_384", "bf16"),
+            offset=williamson5.CONFIG.h0,
         ),
     }
 

@@ -69,6 +69,7 @@ __all__ = [
     "advection1d_mega",
     "burgers1d_mega",
     "swe2d_mega",
+    "swe_sphere_mega",
 ]
 
 
@@ -99,6 +100,7 @@ def _mega_kernel(
     capture,
     storage,
     packed_io,
+    n_const,
 ):
     fmt = prec.fmt
     n_sites = len(sites)
@@ -133,6 +135,8 @@ def _mega_kernel(
     elif has_floor:
         k_active = cols(refs[pos])  # pinned: static profiled splits
         pos += 1
+    const_refs = refs[pos : pos + n_const]
+    pos += n_const
 
     # ---- output refs -----------------------------------------------------
     out_refs = refs[pos : pos + n_state]
@@ -190,7 +194,9 @@ def _mega_kernel(
             prec, sites, k_floor=floor, collect=collect, capture=capture,
             site_ops=site_ops,
         )
-        new = body(st, ops)
+        # read-only grid fields load every substep (cheap VMEM reads) rather
+        # than living across the loop
+        new = body(st, ops, tuple(r[...] for r in const_refs)) if n_const else body(st, ops)
         if not isinstance(new, tuple):
             new = (new,)
         if len(new) != n_state:
@@ -288,6 +294,7 @@ def mega_sweep(
     capture=None,
     interpret: Optional[bool] = None,
     storage: str = "f32",
+    consts: Sequence = (),
 ) -> MegaResult:
     """Run an ENTIRE simulation horizon — ``steps`` substeps with snapshots
     every ``every`` — in one ``pallas_call``.
@@ -308,6 +315,11 @@ def mega_sweep(
         rows as the static datapath splits. None: untracked.
       every: snapshot cadence; ``steps // every`` boundary snapshots (and
         boundary storage roundings) happen inside the kernel.
+      consts: read-only f32 fields of the grid (metric factors, a
+        topography's gradients). They are loaded into the kernel, handed
+        to ``body(state_leaves, ops, consts)`` every substep, and never
+        rounded, snapshotted or returned. Under ``vmap`` an unbatched
+        field is shared by every member. Empty: ``body(state_leaves, ops)``.
 
     Returns a :class:`MegaResult`. ``evidence`` is populated when
     ``collect_evidence`` or ``capture`` asks for it (the tracker fold no
@@ -366,6 +378,7 @@ def mega_sweep(
         ]
     elif has_floor:
         inputs.append(jnp.asarray(tracker.k, jnp.int32).reshape(1, n_sites))
+    inputs += [jnp.asarray(c, jnp.float32) for c in consts]
 
     out_shape = []
     if packed_io:
@@ -413,6 +426,7 @@ def mega_sweep(
                 capture=capture,
                 storage=storage,
                 packed_io=packed_io,
+                n_const=len(consts),
             ),
             out_shape=tuple(out_shape),
             interpret=interpret,
@@ -616,6 +630,52 @@ def swe2d_mega(
         prec=prec, sites=sites, site_ops=site_ops, steps=steps, every=every,
         tracker=tracker, collect_evidence=collect_evidence, capture=capture,
         interpret=interpret, storage=storage,
+    )
+    (out,) = res.state
+    (snaps,) = res.snaps
+    return res._replace(state=out, snaps=snaps)
+
+
+def _swe_sphere_body(cfg, sites):
+    """One whole spherical Lax-Wendroff update in-kernel: the substituted
+    zonal momentum flux on the megakernel's :class:`FusedOps`, every other
+    sub-equation f32 jnp, the grid's metric and topography fields read from
+    the kernel's read-only inputs."""
+    from repro.pde.swe2d import _momentum_flux
+    from repro.pde.swe_sphere import SphereGrid, _sphere_step
+
+    def body(state, ops, consts):
+        (U,) = state
+        U = _sphere_step(
+            U, cfg, SphereGrid(*consts),
+            lambda q1, q3: _momentum_flux(q1, q3, ops, cfg.g, sites),
+        )
+        return (U,)
+
+    return body
+
+
+@functools.partial(jax.jit, static_argnames=_MEGA_STATICS + ("cfg", "site_ops"))
+def swe_sphere_mega(
+    U0, *, cfg, prec, steps, every, sites, site_ops, tracker=None,
+    collect_evidence=False, capture=None, interpret=None, storage="f32",
+):
+    """Whole-horizon shallow-water run on the sphere; ``U0`` is the stacked
+    (3, nlat, nlon) state with longitude on the lanes. The grid's fields
+    enter as the kernel's read-only inputs, shared by every member under
+    ``vmap``. Packed storage takes the XLA-boundary shape of
+    :func:`swe2d_mega`."""
+    from repro.pack.packed import unpack_array
+    from repro.pde.swe_sphere import sphere_grid
+
+    packed = isinstance(U0, PackedArray)
+    lead = unpack_array(U0) if packed else jnp.asarray(U0, jnp.float32)
+    res = mega_sweep(
+        _swe_sphere_body(cfg, sites),
+        (lead,),
+        prec=prec, sites=sites, site_ops=site_ops, steps=steps, every=every,
+        tracker=tracker, collect_evidence=collect_evidence, capture=capture,
+        interpret=interpret, storage=storage, consts=tuple(sphere_grid(cfg)),
     )
     (out,) = res.state
     (snaps,) = res.snaps

@@ -34,7 +34,7 @@ SWE_SITES = ("swe.q1q1", "swe.q3q3", "swe.gq3", "swe.div")
 SWE_OPS = ("mul", "mul", "mul", "div")
 
 
-def _swe_flux_body(sites):
+def _swe_flux_body(sites, g=G_GRAV):
     q1q1_site, q3q3_site, gq3_site, div_site = sites
 
     def body(state, ops):
@@ -42,7 +42,7 @@ def _swe_flux_body(sites):
         t1 = ops.mul(q1, q1, q1q1_site)  # multiplier 1
         t2 = ops.div(t1, q3, div_site)  # flexible divider (quotient envelope)
         t3 = ops.mul(q3, q3, q3q3_site)  # multiplier 2
-        t4 = ops.mul(jnp.full_like(t3, 0.5 * G_GRAV), t3, gq3_site)  # mult 3
+        t4 = ops.mul(jnp.full_like(t3, 0.5 * g), t3, gq3_site)  # mult 3
         return (t2 + t4,)
 
     return body
@@ -60,17 +60,19 @@ def swe_flux_fused(
     collect_evidence=False,
     capture=None,
     interpret=None,
+    g=G_GRAV,
 ):
     """Fused-plane entry: momentum flux + per-site evidence over 2D fields.
 
-    ``block`` defaults to the policy's ``kernel_blocks[:2]``. Returns
+    ``block`` defaults to the policy's ``kernel_blocks[:2]``; ``g`` is the
+    gravity of the flux's pressure term. Returns
     ``(flux, evidence)`` with evidence shaped ``(1, n_sites, 2)`` (the flux
     is one substep of a fused chunk), plus a ``(n_sites, 2, n_bins)``
     exponent-count array when a ``capture`` spec is given.
     """
     block = tuple(prec.kernel_blocks[:2]) if block is None else block
     res = fused.fused_sweep(
-        _swe_flux_body(sites),
+        _swe_flux_body(sites, g),
         (q1, q3),
         prec=prec,
         sites=sites,

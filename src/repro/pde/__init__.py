@@ -1,7 +1,8 @@
 """PDE workloads over the unified solver framework.
 
 The paper's two case studies (``heat1d``, ``swe2d``) plus beyond-paper
-scenario workloads (``heat2d``, ``advection1d``, ``burgers1d``), each a
+scenario workloads (``heat2d``, ``advection1d``, ``burgers1d``, and
+``swe_sphere``: Williamson's shallow water on the sphere), each a
 :class:`~repro.pde.solver.Stepper` registered by name. Generic code drives
 them through :class:`~repro.pde.solver.Simulation`::
 
@@ -23,6 +24,7 @@ from .heat2d import Heat2DConfig, initial_condition_2d
 from .precision_ops import padd, pdiv, pmul, pstore
 from .swe2d import SWEConfig, swe_step
 from .swe2d import simulate as simulate_swe
+from .swe_sphere import SphereConfig
 
 __all__ = [
     # framework
@@ -39,6 +41,7 @@ __all__ = [
     "AdvectionConfig",
     "BurgersConfig",
     "SWEConfig",
+    "SphereConfig",
     "initial_condition_2d",
     "initial_profile",
     "initial_wave",

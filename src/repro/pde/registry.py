@@ -48,7 +48,9 @@ def _load_builtins() -> None:
     if not _builtins_loaded:
         # registering happens at module import; workload modules are listed
         # here (not via the package __init__) to avoid an import cycle
-        from repro.pde import advection1d, burgers1d, heat1d, heat2d, swe2d  # noqa: F401
+        from repro.pde import (  # noqa: F401
+            advection1d, burgers1d, heat1d, heat2d, swe2d, swe_sphere,
+        )
 
         # flag set only on success so a failed import is retried, not masked
         _builtins_loaded = True

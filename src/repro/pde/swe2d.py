@@ -75,16 +75,23 @@ def initial_state(cfg: SWEConfig):
     return jnp.stack([h, hu, hv])
 
 
-def _momentum_flux(q1, q3, ops: StepOps):
+SITES = ("swe.q1q1", "swe.q3q3", "swe.gq3", "swe.div")
+
+
+def _momentum_flux(q1, q3, ops: StepOps, g: float = G, sites=SITES):
     """The paper's substituted equation: q1*q1/q3 + 0.5*g*q3*q3, with its
     multiplications on the policy's multiplier AND its division on the
     policy's flexible divider (``repro.alu`` — the tracked ``swe.div``
     site, split picked under the quotient-range envelope). Every other
-    division in this solver stays on the f32 divider."""
-    t1 = ops.mul(q1, q1, "swe.q1q1")
-    t2 = ops.div(t1, q3, "swe.div")
-    t3 = ops.mul(q3, q3, "swe.q3q3")
-    t4 = ops.mul(jnp.float32(0.5 * G), t3, "swe.gq3")
+    division in this solver stays on the f32 divider. ``sites`` names the
+    (q1*q1, q3*q3, g/2*q3*q3, division) sites; the spherical solver
+    (:mod:`repro.pde.swe_sphere`) reuses the equation under its own names
+    and its own ``g``."""
+    q1q1, q3q3, gq3, div = sites
+    t1 = ops.mul(q1, q1, q1q1)
+    t2 = ops.div(t1, q3, div)
+    t3 = ops.mul(q3, q3, q3q3)
+    t4 = ops.mul(jnp.float32(0.5 * g), t3, gq3)
     return t2 + t4
 
 
@@ -178,7 +185,7 @@ class SWE2DStepper(Stepper):
     precision.
     """
 
-    sites = ("swe.q1q1", "swe.q3q3", "swe.gq3", "swe.div")
+    sites = SITES
     site_ops = ("mul", "mul", "mul", "div")
     failure_mode = "overflow"
     story = "h*h = 2.5e5 at a realistic basin depth overflows E5M10's 65504"
@@ -190,8 +197,18 @@ class SWE2DStepper(Stepper):
     def init_state(self, cfg: SWEConfig):
         return initial_state(cfg)
 
+    def gravity(self, cfg) -> float:
+        """The ``g`` of the substituted equation's pressure term."""
+        return G
+
+    def update(self, U, cfg, mom):
+        """One whole update, the substituted flux computed by ``mom(q1, q3)``
+        — the one method a solver sharing this stepper's planes replaces."""
+        return _lw_step(U, cfg, mom)
+
     def step(self, U, cfg: SWEConfig, ops: StepOps):
-        return _lw_step(U, cfg, lambda q1, q3: _momentum_flux(q1, q3, ops))
+        g, sites = self.gravity(cfg), self.sites
+        return self.update(U, cfg, lambda q1, q3: _momentum_flux(q1, q3, ops, g, sites))
 
     def fused_step(
         self,
@@ -224,6 +241,7 @@ class SWE2DStepper(Stepper):
                 collect_evidence=collect_evidence,
                 capture=capture,
                 interpret=interpret,
+                g=self.gravity(cfg),
             )
             if capture is not None:
                 flux, mom.evidence, mom.counts = res
@@ -232,7 +250,7 @@ class SWE2DStepper(Stepper):
             return flux
 
         def substep(U, _):
-            U = _lw_step(U, cfg, mom)
+            U = self.update(U, cfg, mom)
             if capture is not None:
                 return U, (mom.evidence, mom.counts)
             return U, mom.evidence  # (1, n_sites, 2) per substep, or None

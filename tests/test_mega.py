@@ -21,10 +21,11 @@ from repro.pde.burgers1d import BurgersConfig, initial_wave
 from repro.pde.heat1d import HeatConfig
 from repro.pde.heat2d import Heat2DConfig
 from repro.pde.swe2d import SWEConfig
+from repro.pde.swe_sphere import SphereConfig
 from repro.precision import mega_eligible
 
 TRACKED = dataclasses.replace(PRESETS["r2f2_16"], mode="rr_tracked")
-BUILTINS = ("advection1d", "burgers1d", "heat1d", "heat2d", "swe2d")
+BUILTINS = ("advection1d", "burgers1d", "heat1d", "heat2d", "swe2d", "swe_sphere")
 
 SMALL = {
     "heat1d": HeatConfig(nx=64),
@@ -32,6 +33,7 @@ SMALL = {
     "advection1d": AdvectionConfig(nx=128),
     "burgers1d": BurgersConfig(nx=128),
     "swe2d": SWEConfig(nx=32, ny=32),
+    "swe_sphere": SphereConfig(nlon=32, nlat=16, dt=120.0),
 }
 
 
@@ -42,6 +44,22 @@ def assert_bits_equal(a, b):
     a, b = np.asarray(a), np.asarray(b)
     assert a.dtype == b.dtype == np.float32
     np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
+
+
+#: XLA's CPU backend contracts ``a * b + c`` into one fused multiply-add
+#: inside a fusion, so where the two planes fuse an update differently their
+#: float32 results part in the last bits. swe_sphere's update does (at 32x16
+#: over 20 steps: f32 3.2e-7, bf16 and deploy 2.9e-6 of the value, a few
+#: elements of 1,536); its states are held to float32 rounding instead of
+#: bits, its trackers still exactly.
+FMA_CLOSE = ("swe_sphere",)
+
+
+def assert_planes_agree(name, a, b):
+    if name in FMA_CLOSE:
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=0)
+    else:
+        assert_bits_equal(a, b)
 
 
 def _pair(name, prec, steps=20, every=6, **kw):
@@ -70,8 +88,8 @@ class TestMegaParity:
         same boundary storage rounding as the chunked plane, so states and
         snapshots must agree bit for bit — NaN patterns included."""
         fus, meg = _pair(name, PRESETS[preset])
-        assert_bits_equal(fus.state, meg.state)
-        assert_bits_equal(fus.snapshots, meg.snapshots)
+        assert_planes_agree(name, fus.state, meg.state)
+        assert_planes_agree(name, fus.snapshots, meg.snapshots)
         assert meg.tracker is None
 
     @pytest.mark.parametrize("name", BUILTINS)
@@ -82,8 +100,8 @@ class TestMegaParity:
         bit-exact AND the final per-site splits, EMAs, and §5.3 counters
         are identical — not merely close."""
         fus, meg = _pair(name, TRACKED)
-        assert_bits_equal(fus.state, meg.state)
-        assert_bits_equal(fus.snapshots, meg.snapshots)
+        assert_planes_agree(name, fus.state, meg.state)
+        assert_planes_agree(name, fus.snapshots, meg.snapshots)
         for field in ("k", "hi_ema", "lo_ema", "overflow_steps", "shrink_steps"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(fus.tracker.state, field)),
@@ -96,7 +114,7 @@ class TestMegaParity:
         """deploy (bf16 datapath, shadow tracker) evolves its tracker
         on-chip too; arithmetic is split-independent so everything matches."""
         fus, meg = _pair(name, PRESETS["deploy"])
-        assert_bits_equal(fus.state, meg.state)
+        assert_planes_agree(name, fus.state, meg.state)
         np.testing.assert_array_equal(
             np.asarray(fus.tracker.state.k), np.asarray(meg.tracker.state.k)
         )

@@ -43,6 +43,7 @@ STEPPER_SMALL_CFG = {
     "advection1d": {"nx": 64},
     "burgers1d": {"nx": 64},
     "swe2d": {"nx": 16, "ny": 16},
+    "swe_sphere": {"nlon": 16, "nlat": 8},
 }
 
 

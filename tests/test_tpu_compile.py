@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import burgers1d, heat1d, heat2d, swe2d
+from repro.configs import burgers1d, heat1d, heat2d, swe2d, williamson5
 from repro.core.flexformat import E8M23
 from repro.core.policy import PRESETS, tracker_init
 from repro.kernels import mega
@@ -29,6 +29,7 @@ from repro.kernels.r2f2_matmul import r2f2_matmul_pallas
 from repro.kernels.r2f2_quantize import r2f2_quantize_pallas
 from repro.kernels.swe_flux import SWE_OPS, SWE_SITES, swe_flux_fused
 from repro.pack import PackedArray, payload_dtype
+from repro.pde.swe_sphere import SITES as SPHERE_SITES
 from repro.profile.capture import CaptureSpec
 
 
@@ -211,6 +212,38 @@ def _swe2d_mega_packed(mode):
     return fn, [((3, c.nx, c.ny), jnp.float32)]
 
 
+def _sphere_prec(mode):
+    """The cell's R2F2-16 split ``<3,8,4>`` for the R2F2 modes."""
+    p = _prec(mode)
+    if p.mode.startswith("rr"):
+        p = dataclasses.replace(p, fmt=PRESETS["r2f2_16_384"].fmt)
+    return p
+
+
+def _swe_sphere_mega(mode, capture=None, steps=williamson5.STEPS_PER_DAY, every=2700):
+    """The ``williamson5.ens51`` cell's program: 51 members under ``vmap``,
+    (3, 64, 128) each, one model day, the grid's fields as shared read-only
+    kernel inputs; the longitude wrap and the half-turn pole rows are lane
+    rolls inside the kernel."""
+    c, p = williamson5.CONFIG, _sphere_prec(mode)
+    tr = tracker_init(4, p.fmt) if _tracked(mode) else None
+
+    def member(u):
+        return mega.swe_sphere_mega(
+            u, cfg=c, prec=p, steps=steps, every=every,
+            sites=SPHERE_SITES, site_ops=SWE_OPS, tracker=tr, capture=capture,
+            interpret=False,
+        )
+
+    return jax.vmap(member), [((51, 3, c.nlat, c.nlon), jnp.float32)]
+
+
+def _swe_sphere_mega_capture(mode):
+    """Capture streams every substep's evidence out of VMEM as one block, so
+    it compiles for a short horizon (a day's would need 44 MB of VMEM)."""
+    return _swe_sphere_mega(mode, capture=CaptureSpec(), steps=8, every=4)
+
+
 PDE_KERNELS = {
     "heat1d_sweep": _heat1d_sweep,
     "heat2d_sweep": _heat2d_sweep,
@@ -223,6 +256,8 @@ PDE_KERNELS = {
     "heat1d_sweep+packed": _heat1d_sweep_packed,
     "swe2d_mega+capture": _swe2d_mega_capture,
     "swe2d_mega+packed": _swe2d_mega_packed,
+    "swe_sphere_mega": _swe_sphere_mega,
+    "swe_sphere_mega+capture": _swe_sphere_mega_capture,
 }
 
 
