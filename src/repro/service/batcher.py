@@ -28,6 +28,18 @@ Pallas kernel chunks; the bucket exploits exactly that seam:
   ``RequestRecord.state``/``.tracker`` while it runs. Snapshots come to
   the host in one transfer per chunk. The batch's width is the member
   count either way, so the compiled chunk program is the same one.
+* **the next chunk runs ahead** — when the bucket is the only live one,
+  nothing is queued and no member ends with the current chunk, its
+  membership cannot change before the next one; that chunk is then
+  dispatched on the current chunk's outputs before the host waits for
+  them, so the device runs it while the host syncs, fetches and streams
+  the current one, and the next pump finds it done. Chunk boundaries stay
+  at the members' events, so joins, evictions and streaming are exactly as
+  without it: a ``join`` or ``remove`` (drain, eviction, failure) drops
+  the chunk run ahead, and the bucket runs its next chunk anew from the
+  resident batch. Each dispatch also starts the host copies the chunk's
+  end will need (its frames when a snapshot is due, its batch when a
+  member drains).
 * **compiled-chunk cache** — chunk programs are jitted once per
   ``(bucket key, chunk steps, member count)`` and reused across repacks, so
   steady-state traffic pays tracing cost only when the packing shape
@@ -50,7 +62,7 @@ from __future__ import annotations
 
 import time
 from collections import OrderedDict
-from typing import Callable, List, Optional, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -74,6 +86,21 @@ def _stack(trees):
 def _row(tree, i: int):
     """Member ``i``'s row of a stacked host pytree, as arrays of its own."""
     return jax.tree_util.tree_map(lambda x: np.array(x[i]), tree)
+
+
+def _copy_to_host(tree) -> None:
+    """Start ``tree``'s device-to-host copies without waiting for them."""
+    for x in jax.tree_util.tree_leaves(tree):
+        x.copy_to_host_async()
+
+
+class _Call(NamedTuple):
+    """One dispatched chunk program call, not yet synced."""
+
+    out: Any  # (state, snapshots, tracker) on the device
+    steps: int
+    fresh: bool
+    dispatch_s: float  # host seconds the dispatch took
 
 
 class ChunkCompiler:
@@ -146,6 +173,10 @@ class Bucket:
         self.key = key
         self.members: List[RequestRecord] = []
         self._batch = None
+        #: the next chunk's call, dispatched ahead on the last chunk's
+        #: outputs (or the exception that dispatch raised, for the next
+        #: advance to raise)
+        self._ahead = None
         self._rows: List[RequestRecord] = []
         #: host copies of the batch's state and tracker, each fetched at most
         #: once per chunk (None: not fetched since the batch last changed)
@@ -176,6 +207,7 @@ class Bucket:
             self.members.append(rec)
             rec.status = "running"
             self._change = self._change or "join"
+            self._ahead = None
 
     def remove(self, leaving: List[RequestRecord], reason: str) -> None:
         """The only way members leave (``reason``: ``drain``, ``evict`` or
@@ -195,6 +227,7 @@ class Bucket:
         for m in leaving:
             self.members.remove(m)
         self._change = self._change or reason
+        self._ahead = None
         if not self.members:  # nothing left to run: free the device copy
             self._batch, self._rows, self._host = None, [], [None, None]
 
@@ -229,62 +262,102 @@ class Bucket:
             m.state = m.tracker = None
             m.resident_in = self
 
-    def next_chunk(self) -> int:
-        """Steps until the earliest member event — the next chunk's length."""
-        return min(m.steps_to_next_event() for m in self.members)
+    def next_chunk(self, after: int = 0) -> int:
+        """Steps until the earliest member event, ``after`` steps from now —
+        the length of the chunk that starts there."""
+        return min(m.steps_to_next_event(after) for m in self.members)
+
+    def _dispatch(self, fn, fresh: bool, steps: int, batch, after: int = 0) -> _Call:
+        """Call ``fn`` on ``batch`` for a chunk of ``steps`` that starts
+        ``after`` steps from now, and start the host copies its end needs:
+        the frames if a member's snapshot falls due there, the batch if a
+        member drains there."""
+        t0 = time.perf_counter()
+        with obs.span("service.dispatch", steps=steps, ahead=after > 0):
+            out = fn(*batch)
+        ends = [(m, m.elapsed + after + steps) for m in self.members]
+        if any(end % m.every == 0 for m, end in ends):
+            _copy_to_host(out[1])
+        if any(end == m.steps for m, end in ends):
+            _copy_to_host((out[0], out[2]))
+        return _Call(out, steps, fresh, time.perf_counter() - t0)
 
     def advance(
         self,
         compiler: ChunkCompiler,
         metrics: ServiceMetrics,
         sharded: Optional[bool] = None,
+        alone: bool = False,
     ) -> List[RequestRecord]:
         """Run one chunk for every member; returns the members that drained.
 
         ``sharded=None`` auto-detects: bucket members ride the logical
         ``batch`` axis whenever a ``dist.sharding.axis_rules`` mesh context
         is active (``repro.dist.sharding.active_mesh``), so the same service
-        loop spreads buckets over a mesh's data axes unchanged.
+        loop spreads buckets over a mesh's data axes unchanged. ``alone``:
+        the scheduler sees no other live bucket and an empty queue, so the
+        next chunk may run ahead (module docstring).
         """
         if not self.members:
             return []
         mesh = active_mesh()
         if sharded is None:
             sharded = mesh is not None
-        chunk = self.next_chunk()
+        mesh = mesh if sharded else None
         n = len(self.members)
         ids = self.member_ids()
         sim = self.members[0].sim  # identical (stepper, cfg, prec) by key
         tracked = self.members[0].tracked
 
-        reason = "first" if self._batch is None else self._change
-        if reason is not None:
-            with obs.span("service.stack", members=ids, reason=reason):
-                self._restack(tracked, sharded)
-        self._change = None
-
-        fn, fresh = compiler.get(
-            sim, self.key, chunk, n, sharded, mesh=mesh if sharded else None
-        )
+        call, self._ahead = self._ahead, None
+        if isinstance(call, Exception):  # this chunk's dispatch ahead failed
+            raise call
+        ran_ahead = call is not None
+        reason = None
+        if not ran_ahead:
+            reason = "first" if self._batch is None else self._change
+            if reason is not None:
+                with obs.span("service.stack", members=ids, reason=reason):
+                    self._restack(tracked, sharded)
+            self._change = None
+            chunk = self.next_chunk()
+            fn, fresh = compiler.get(sim, self.key, chunk, n, sharded, mesh=mesh)
+        else:
+            chunk, fresh = call.steps, call.fresh
         with obs.span(
             "service.chunk",
             bucket=self.key.short(),
             members=ids,
             steps=chunk,
             compile=fresh,
+            ahead=ran_ahead,
         ):
+            if not ran_ahead:
+                call = self._dispatch(fn, fresh, chunk, self._batch)
+            if alone and all(m.remaining > chunk for m in self.members):
+                # the membership holds past this chunk: queue the next
+                # one behind it on the device before waiting for it
+                nxt = self.next_chunk(after=chunk)
+                try:
+                    nfn, nfresh = compiler.get(sim, self.key, nxt, n, sharded, mesh=mesh)
+                    self._ahead = self._dispatch(
+                        nfn, nfresh, nxt, (call.out[0], call.out[2]), after=chunk)
+                except Exception as e:  # raised where that chunk would run
+                    self._ahead = e
             t0 = time.perf_counter()
-            with obs.span("service.dispatch"):
-                out = fn(*self._batch)
             with obs.span("service.sync"):
-                out_state, out_snaps, out_tracker = jax.block_until_ready(out)
-            dt = time.perf_counter() - t0
+                out_state, out_snaps, out_tracker = jax.block_until_ready(call.out)
+            # the host's time in this chunk's own calls: a chunk run ahead
+            # is not charged for the host work it overlapped
+            dt = call.dispatch_s + time.perf_counter() - t0
         self._batch, self._host = (out_state, out_tracker), [None, None]
         metrics.observe_chunk(self.key, n, chunk, dt, compiled=fresh)
         if reason is None:
             metrics.resident_chunks += 1
         else:
             metrics.restacks += 1
+        if ran_ahead:
+            metrics.chunks_ahead += 1
         mon = health.active()
         o = obs.active()
         # per-chunk tracker consumers read the members' rows of one host

@@ -20,7 +20,9 @@ accumulates everything the ISSUE's production story needs to be judged by:
   evicted / resumed / snapshots streamed;
 * **resident batch** — chunk calls whose bucket batch was rebuilt because
   its membership changed (``restacks``) against those that reused the
-  previous chunk's outputs on the device (``resident_chunks``).
+  previous chunk's outputs on the device (``resident_chunks``), and of
+  those the chunk calls a lone bucket dispatched ahead, before it waited
+  for the chunk before (``chunks_ahead``).
 
 Since PR 9 this class is a thin consumer of a
 :class:`repro.obs.MetricsRegistry` — every counter/histogram lives in the
@@ -66,6 +68,8 @@ _LIFECYCLE = {
                  "chunk calls whose bucket batch was rebuilt (membership changed)"),
     "resident_chunks": ("repro_service_resident_chunks_total",
                         "chunk calls that reused the bucket's resident batch"),
+    "chunks_ahead": ("repro_service_chunks_ahead_total",
+                     "chunk calls dispatched before the previous chunk was synced"),
 }
 
 _FLOAT_COUNTERS = {
@@ -228,6 +232,7 @@ class ServiceMetrics:
             "compile_seconds": self.compile_seconds,
             "restacks": self.restacks,
             "resident_chunks": self.resident_chunks,
+            "chunks_ahead": self.chunks_ahead,
             "throughput_steps_per_s": self.throughput(),
             "chunk_latency_p50_us": self.latency_us(50),
             "chunk_latency_p99_us": self.latency_us(99),
@@ -245,7 +250,8 @@ class ServiceMetrics:
             f"  requests    submitted={s['submitted']} completed={s['completed']} "
             f"rejected={s['rejected']} failed={s['failed']} "
             f"evicted={s['evicted']} resumed={s['resumed']}",
-            f"  chunks      n={s['chunks']} p50={s['chunk_latency_p50_us']:.0f}us "
+            f"  chunks      n={s['chunks']} ahead={s['chunks_ahead']} "
+            f"p50={s['chunk_latency_p50_us']:.0f}us "
             f"p99={s['chunk_latency_p99_us']:.0f}us busy={s['busy_seconds']:.2f}s",
             f"  compile     n={s['compiles']} {s['compile_seconds']:.2f}s "
             f"(excluded from latency/throughput)",

@@ -201,12 +201,14 @@ class RequestRecord:
     def remaining(self) -> int:
         return self.steps - self.elapsed
 
-    def steps_to_next_event(self) -> int:
+    def steps_to_next_event(self, after: int = 0) -> int:
         """Steps until this member next needs the bucket to pause — its own
-        snapshot point or its horizon, whichever is sooner. The bucket chunk
-        is the min of this over members (continuous batching never steps a
-        member past one of its events)."""
-        return min(self.remaining, self.every - (self.elapsed % self.every))
+        snapshot point or its horizon, whichever is sooner — counted from
+        ``after`` steps past ``elapsed``. The bucket chunk is the min of this
+        over members (continuous batching never steps a member past one of
+        its events)."""
+        at = self.elapsed + after
+        return min(self.steps - at, self.every - (at % self.every))
 
     def snapshot_due(self) -> bool:
         """Does the current ``elapsed`` coincide with one of the snapshot

@@ -27,6 +27,9 @@ and validated precision artifact; the service owns the packing):
 
 ``pump()`` is one cooperative scheduling iteration (fill → advance one
 bucket one chunk → fill); ``run_until_idle()`` drives it to completion.
+When the pump sees one live bucket and an empty queue, that bucket
+dispatches its next chunk before it waits for the current one, so the
+device runs it while the host works (``Bucket.advance``'s ``alone``).
 Single-process and synchronous by design — the batching/scheduling
 semantics are the subject here, not an async runtime; a server front-end
 can pump this loop from any thread.
@@ -145,7 +148,8 @@ class SimService:
                 mon.note_occupancy(self.queued, self.active_members)
             try:
                 drained = bucket.advance(
-                    self._compiler, self.metrics, sharded=self.config.sharded
+                    self._compiler, self.metrics, sharded=self.config.sharded,
+                    alone=len(buckets) == 1 and not self._queue,
                 )
             except Exception as e:  # compile/runtime failure: fail the members
                 failed = list(bucket.members)

@@ -472,13 +472,14 @@ class TestResidentBatch:
         assert m.registry.counter("repro_service_resident_chunks_total").total() == 7
 
     @pytest.mark.parametrize("stepper", sorted(SMALL_CFGS))
-    def test_tracker_telemetry_matches_chunk_outputs(self, stepper):
+    def test_tracker_telemetry_matches_chunk_outputs(self, stepper, monkeypatch):
         """With telemetry on, each request's per-chunk tracker series is its
         row of every chunk's stacked output tracker, entry for entry."""
         cfg = SMALL_CFGS[stepper]
         s0b = _scaled(Simulation(stepper, cfg, TRACKED).stepper.init_state(cfg), 0.5)
         svc = SimService(ServiceConfig())
         rows = {}  # request id -> [(step, k, grew, shrank)] sliced from the outputs
+        calls = {}  # id of a call's outputs -> (outputs, members, chunk steps)
         get = svc._compiler.get
 
         def spy(sim, key, chunk, *args, **kw):
@@ -487,17 +488,28 @@ class TestResidentBatch:
             def run(*xs):
                 out = fn(*xs)
                 (bucket,) = svc._live_buckets()
-                st = out[2].state
-                for i, m in enumerate(bucket.members):
-                    rows.setdefault(m.id, []).append((
-                        m.elapsed + chunk, np.asarray(st.k[i]),
-                        np.asarray(st.overflow_steps[i]), np.asarray(st.shrink_steps[i]),
-                    ))
+                calls[id(out)] = (out, list(bucket.members), chunk)
                 return out
 
             return run, fresh
 
+        sync = jax.block_until_ready
+
+        def synced(x):
+            # a chunk's rows as the bucket syncs it: a chunk run ahead and
+            # dropped at a join is never synced, so it is no member's chunk
+            if id(x) in calls:
+                out, members, chunk = calls.pop(id(x))
+                st = out[2].state
+                for i, m in enumerate(members):
+                    rows.setdefault(m.id, []).append((
+                        m.elapsed + chunk, np.asarray(st.k[i]),
+                        np.asarray(st.overflow_steps[i]), np.asarray(st.shrink_steps[i]),
+                    ))
+            return sync(x)
+
         svc._compiler.get = spy
+        monkeypatch.setattr(jax, "block_until_ready", synced)
         obs.enable()
         try:
             hA = svc.submit(SimRequest(stepper, steps=24, precision=TRACKED, cfg=cfg,
@@ -524,6 +536,175 @@ class TestResidentBatch:
                 assert s.k == [int(k[i]) for _, k, _, _ in expected]
                 assert s.grew == [int(g[i]) for _, _, g, _ in expected]
                 assert s.shrank == [int(r[i]) for _, _, _, r in expected]
+
+
+def _assert_bits_equal(a, b):
+    def same(x, y):
+        x, y = np.asarray(x), np.asarray(y)
+        assert (x.dtype, x.shape) == (y.dtype, y.shape)
+        assert x.tobytes() == y.tobytes()
+
+    jax.tree_util.tree_map(same, a, b)
+
+
+#: (stepper, mode, execution, storage, steps) at a cadence of 2 steps:
+#: every stepper x mode on the served plane ("auto": the megakernel), one
+#: quantized-storage case, one remainder horizon, the fused and reference
+#: planes
+AHEAD_CASES = [
+    (stepper, mode, "auto", "f32", 16)
+    for stepper in sorted(SMALL_CFGS)
+    for mode in ("rr_tracked", "deploy", "f32")
+] + [
+    ("heat1d", "rr_tracked", "auto", "quantized", 16),
+    ("burgers1d", "rr_tracked", "auto", "f32", 17),
+    ("heat2d", "rr_tracked", "fused", "f32", 16),
+    ("advection1d", "rr_tracked", "reference", "f32", 16),
+]
+
+
+class TestChunksAhead:
+    @pytest.mark.parametrize("stepper,mode,execution,storage,steps", AHEAD_CASES)
+    def test_lone_request_runs_ahead_bit_identically(
+        self, stepper, mode, execution, storage, steps
+    ):
+        """A request alone in the service runs every chunk after its first
+        ahead, one snapshot interval a chunk as before, and its state,
+        frames, frame steps, tracker, final k and §5.3 counters equal its
+        solo run and those of a twin served beside a sibling bucket, where
+        no chunk runs ahead, bit for bit."""
+        every = 2
+        prec = dict((m[0], m[1]) for m in MODES)[mode]
+        cfg = SMALL_CFGS[stepper]
+
+        def serve(twins, **config):
+            svc = SimService(ServiceConfig(**config))
+            hs = [svc.submit(SimRequest(stepper, steps=steps, precision=prec, cfg=cfg,
+                                        snapshot_every=every, execution=execution,
+                                        storage=storage))
+                  for _ in range(twins)]
+            svc.run_until_idle()
+            assert all(h.status == "done" for h in hs)
+            return hs[0].result(), svc.metrics
+
+        chunks = -(-steps // every)
+        lone, m = serve(1)
+        assert (lone.chunks, m.chunks, m.chunks_ahead) == (chunks, chunks, chunks - 1)
+        assert m.registry.counter("repro_service_chunks_ahead_total").total() == chunks - 1
+        assert m.summary()["chunks_ahead"] == chunks - 1
+        assert f"ahead={chunks - 1}" in m.report()
+        beside, m = serve(2, max_bucket=1)  # two live buckets take turns
+        assert (beside.chunks, m.chunks_ahead) == (chunks, 0)
+        solo = Simulation(stepper, cfg, prec).run(
+            steps, snapshot_every=every, execution=execution, storage=storage)
+
+        assert lone.snapshot_steps == beside.snapshot_steps == list(
+            range(every, steps + 1, every))
+        for other in (beside, solo):
+            _assert_bits_equal(lone.state, other.state)
+            _assert_bits_equal(lone.snapshots, list(other.snapshots))
+            assert (lone.tracker is None) == (other.tracker is None)
+            if lone.tracker is not None:
+                _assert_bits_equal(lone.tracker.state, other.tracker.state)
+        assert (lone.final_k, lone.adjustments) == (beside.final_k, beside.adjustments)
+        if mode == "rr_tracked":
+            assert lone.final_k is not None
+
+    @pytest.mark.parametrize("condition", ["two_buckets", "queued"])
+    def test_no_chunk_runs_ahead_beside_other_work(self, condition):
+        """Another live bucket or a queued request: no chunk runs ahead
+        while it is there. With one slot, B waits in the queue through A's
+        8 chunks and then runs 7 of its own 8 ahead, alone."""
+        cfg = HeatConfig(nx=48)
+        config = {"two_buckets": {"max_bucket": 1},
+                  "queued": {"max_active_members": 1}}[condition]
+        svc = SimService(ServiceConfig(**config))
+        scales = (None, 0.5)
+        hs = [svc.submit(SimRequest(
+            "heat1d", steps=16, precision=TRACKED, cfg=cfg, snapshot_every=2,
+            state0=None if s is None else _scaled(
+                Simulation("heat1d", cfg, TRACKED).stepper.init_state(cfg), s)))
+            for s in scales]
+        svc.run_until_idle()
+        m = svc.metrics
+        assert m.chunks == 16
+        assert m.chunks_ahead == {"two_buckets": 0, "queued": 7}[condition]
+        for h, scale in zip(hs, scales):
+            solo = _solo("heat1d", "rr_tracked", 16, 2, scale)
+            np.testing.assert_array_equal(np.stack(h.snapshots), np.asarray(solo.snapshots))
+            _assert_tree_equal(h.result().state, solo.state)
+            _assert_trackers_equal(h.result().tracker, solo.tracker)
+
+    def test_frames_stream_one_a_pump(self):
+        """Running ahead leaves streaming as it was: a lone request's frames
+        reach its stream one a pump, as each interval ends."""
+        svc = SimService(ServiceConfig())
+        h = svc.submit(SimRequest("heat1d", steps=16, precision=TRACKED,
+                                  cfg=HeatConfig(nx=48), snapshot_every=2))
+        per_pump = []
+        while svc.pump():
+            per_pump.append([(e.kind, e.step) for e in h.stream.drain()])
+        assert per_pump == [[("snapshot", s)] for s in range(2, 16, 2)] + [
+            [("snapshot", 16), ("done", 16)]]
+        assert svc.metrics.chunks_ahead == 7
+
+    @pytest.mark.parametrize("change", ["join", "evict"])
+    def test_membership_change_drops_the_chunk_run_ahead(self, change, tmp_path):
+        """A join or an eviction after a lone bucket's pump drops the chunk
+        it ran ahead: the next chunk runs anew from the batch of the last
+        one, and every member matches its solo run."""
+        cfg = BurgersConfig(nx=48)
+        svc = SimService(ServiceConfig(ckpt_dir=str(tmp_path)))
+
+        def req(scale):
+            init = Simulation("burgers1d", cfg, TRACKED).stepper.init_state(cfg)
+            return SimRequest("burgers1d", steps=30, precision=TRACKED, cfg=cfg,
+                              snapshot_every=10, state0=_scaled(init, scale),
+                              execution="reference")
+
+        hA = svc.submit(req(1.0))
+        hB = svc.submit(req(0.5))
+        svc.pump()  # A and B at 10, their second chunk dispatched ahead
+        (bucket,) = svc._live_buckets()
+        assert bucket._ahead is not None
+        if change == "join":
+            hC = svc.submit(req(1.5))
+            svc.pump()  # C joins: A and B's chunk from 10 runs anew, with C
+            assert hA._record.elapsed == hB._record.elapsed == 20
+            assert hC._record.elapsed == 10
+            served = ((hA, 1.0), (hB, 0.5), (hC, 1.5))
+        else:
+            svc.evict(hA.id)
+            assert hA._record.elapsed == 10 and bucket._ahead is None
+            served = ((hA, 1.0), (hB, 0.5))
+        svc.run_until_idle()
+        for h, scale in served:
+            assert h.status == "done"
+            solo = Simulation("burgers1d", cfg, TRACKED).run(
+                30, snapshot_every=10, state0=_scaled(
+                    Simulation("burgers1d", cfg, TRACKED).stepper.init_state(cfg), scale))
+            np.testing.assert_array_equal(np.stack(h.snapshots), np.asarray(solo.snapshots))
+            _assert_tree_equal(h.result().state, solo.state)
+            _assert_trackers_equal(h.result().tracker, solo.tracker)
+
+    def test_failure_of_a_chunk_run_ahead_surfaces_at_its_chunk(self):
+        """A chunk whose dispatch ahead raises fails its members in the pump
+        that would have run it: the chunk before is streamed first, and
+        the members leave with its state."""
+        cfg = SMALL_CFGS["heat1d"]
+        svc = SimService(ServiceConfig())
+        h = svc.submit(SimRequest("heat1d", steps=12, precision="f32", cfg=cfg,
+                                  snapshot_every=4, execution="reference"))
+        _fail_second_chunk(svc, h._record.key)
+        assert svc.pump()
+        assert [(e.kind, e.step) for e in h.stream.drain()] == [("snapshot", 4)]
+        with pytest.raises(RuntimeError, match="injected chunk failure"):
+            svc.pump()
+        rec = h._record
+        assert h.status == "failed" and rec.elapsed == 4
+        _assert_tree_equal(rec.state, _solo("heat1d", "f32", 4, 4).state)
+        assert [e.kind for e in h.stream.drain()] == ["failed"]
+        assert svc.active_members == 0
 
 
 # ---------------------------------------------------------------------------

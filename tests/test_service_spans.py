@@ -57,6 +57,11 @@ def test_served_path_spans(profiled):
 
     for name in ("service.dispatch", "service.sync"):
         assert all(_inside(s, by["service.chunk"]) for s in by[name])
+    # A and B alone queue their second chunk behind their first; C's join
+    # drops it, so no chunk ran ahead
+    ahead = [s for s in by["service.dispatch"] if s.stats["ahead"]]
+    assert [s.stats["steps"] for s in ahead] == [EVERY]
+    assert not any(s.stats["ahead"] for s in by["service.chunk"])
     assert all(_inside(s, by["service.pump"]) for s in by["service.chunk"])
     roots = by["service.pump"] + by["service.submit"]
     for s in spans:
@@ -108,3 +113,31 @@ def test_results_bit_identical_with_profiler_on_and_off(profiled):
         for sa, sb in zip(ra.snapshots, rb.snapshots):
             np.testing.assert_array_equal(
                 np.asarray(sa).view(np.uint32), np.asarray(sb).view(np.uint32))
+
+
+def test_lone_request_runs_its_chunks_ahead(profiled):
+    """A lone request's chunks after its first are dispatched ahead, each
+    inside the span of the chunk before, ahead of that chunk's sync, and
+    its pumps still stream one frame each."""
+
+    def serve():
+        svc = SimService(ServiceConfig())
+        svc.submit(SimRequest("heat1d", steps=STEPS, overrides={"nx": 48},
+                              precision="r2f2_16", snapshot_every=EVERY // 2))
+        svc.run_until_idle()
+        return svc.metrics
+
+    metrics, spans = profiled(serve)
+    by = {name: [s for s in spans if s.name == name]
+          for name in ("service.pump", "service.chunk", "service.dispatch",
+                       "service.snapshot")}
+    chunks = STEPS // (EVERY // 2)
+    assert [bool(s.stats["ahead"]) for s in by["service.chunk"]] == [False] + [True] * (
+        chunks - 1)
+    ahead = [s for s in by["service.dispatch"] if s.stats["ahead"]]
+    assert len(ahead) == metrics.chunks_ahead == chunks - 1
+    syncs = [s for s in spans if s.name == "service.sync"]
+    for d, c, sync in zip(ahead, by["service.chunk"], syncs):
+        assert _inside(d, [c]) and d.end_ns <= sync.start_ns
+    pumps = [p for p in by["service.pump"] if any(_inside(c, [p]) for c in by["service.chunk"])]
+    assert [sum(_inside(s, [p]) for s in by["service.snapshot"]) for p in pumps] == [1] * chunks
