@@ -10,8 +10,8 @@ One chip, three phases, all in this one process:
   configured sizes and horizons (``repro.configs``) under f32, E5M10,
   R2F2-16 and rr_tracked, on the reference, chunked-fused and megakernel
   execution planes, each named explicitly. Each result is judged against
-  the f32 reference plane with the benchmark's own verdict
-  (``benchmarks.bench_pde.measure``): E5M10 must fail, R2F2-16 and
+  the f32 reference plane with the paper-pattern verdict
+  (``repro.pde.measure``): E5M10 must fail, R2F2-16 and
   rr_tracked must match f32. Each kernel plane is also compared with the
   reference plane of the same mode on the same chip.
 * ``ensemble`` — ``run_ensemble`` of 64 swe2d members on the megakernel
@@ -99,8 +99,7 @@ def phase_planes(steppers=PLANE_STEPPERS, steps=None, cfgs=None):
     """Every (stepper, mode, plane) through ``Simulation.run``."""
     import numpy as np
 
-    from benchmarks.bench_pde import measure, observe, scenarios
-    from repro.pde import Simulation
+    from repro.pde import Simulation, measure, observe, scenarios
 
     table = scenarios()
     failures = []

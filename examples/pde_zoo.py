@@ -7,9 +7,8 @@ Drives every workload through the shared ``repro.pde.solver.Simulation``
 (no per-workload code): f32 reference, the failing E5M10 baseline, 16-bit
 R2F2, and a *tracked* R2F2 run whose final per-site splits are printed —
 the paper's precision-adjust unit carried across the whole simulation.
-Scenario shapes/steps/metric offsets come from the same table the benchmark
-suite uses (``benchmarks.bench_pde.scenarios``), so the zoo and
-``BENCH_pde.json`` can never disagree about a workload. With
+Scenario shapes/steps/metric offsets and the verdict come from
+``repro.pde.scenarios``, the table ``chip_smoke.py`` judges with. With
 ``--ensemble N``, each scenario also runs a vmapped N-member ensemble of
 scaled initial conditions (add a sharding mesh via dist.sharding to spread
 it over devices).
@@ -38,19 +37,19 @@ autotuner would deploy::
 
 import argparse
 import dataclasses
-import pathlib
-import sys
 
 import numpy as np
 
-# examples/ are run as scripts; the bench scenario table lives in the
-# repo-root `benchmarks` package
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
-
-from benchmarks.bench_pde import Scenario, measure, observe, scenarios  # noqa: E402
-
-from repro.precision import PRESETS  # noqa: E402
-from repro.pde import Simulation, get_stepper, known_steppers  # noqa: E402
+from repro.precision import PRESETS
+from repro.pde import (
+    Scenario,
+    Simulation,
+    get_stepper,
+    known_steppers,
+    measure,
+    observe,
+    scenarios,
+)
 
 TRACKED = dataclasses.replace(PRESETS["r2f2_16"], mode="rr_tracked")
 
@@ -78,7 +77,7 @@ def main():
 
     for name in names:
         stepper = get_stepper(name)
-        # steppers registered outside the bench table still run, on defaults
+        # steppers registered outside the scenario table still run, on defaults
         sc = table.get(name) or Scenario(cfg=stepper.default_config(), steps=400)
         print(f"\n=== {name} [{stepper.failure_mode}] — {stepper.story}"
               f" (execution={args.execution})")

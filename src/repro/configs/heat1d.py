@@ -8,5 +8,4 @@ floor late in the simulation — the paper's underflow failure mode.
 from repro.pde.heat1d import HeatConfig
 
 CONFIG = HeatConfig(nx=128, init="sin", alpha=1e-5, cfl=0.4, amplitude=500.0, modes=3)
-CONFIG_EXP = HeatConfig(nx=128, init="exp", alpha=1e-5, cfl=0.4)
-BENCH_STEPS = {"sin": 4000, "exp": 16000}
+BENCH_STEPS = 4000

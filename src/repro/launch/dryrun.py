@@ -17,7 +17,7 @@ For every (architecture x input-shape) cell, on BOTH the single-pod
         compiled.cost_analysis()     # FLOPs/bytes for the roofline
 
 Results (memory/FLOP/collective-bytes per cell) land in
-``artifacts/dryrun/<cell>.json`` — benchmarks/roofline.py reads them.
+``artifacts/dryrun/<cell>.json``.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-405b --shape train_4k

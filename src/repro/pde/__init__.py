@@ -22,6 +22,7 @@ from .heat1d import HeatConfig, heat_step
 from .heat1d import simulate as simulate_heat
 from .heat2d import Heat2DConfig, initial_condition_2d
 from .precision_ops import padd, pdiv, pmul, pstore
+from .scenarios import Scenario, measure, observe, scenarios
 from .swe2d import SWEConfig, swe_step
 from .swe2d import simulate as simulate_swe
 from .swe_sphere import SphereConfig
@@ -35,6 +36,11 @@ __all__ = [
     "register_stepper",
     "get_stepper",
     "known_steppers",
+    # the paper-pattern verdict, one scenario per stepper
+    "Scenario",
+    "scenarios",
+    "observe",
+    "measure",
     # workload configs + shims
     "HeatConfig",
     "Heat2DConfig",

@@ -2,8 +2,8 @@
 
 Mirrors :mod:`repro.precision.registry`: every scenario workload registers a
 :class:`repro.pde.solver.Stepper` under a short name, and everything generic
-— the :class:`~repro.pde.solver.Simulation` driver, the per-stepper benchmark
-suite (``benchmarks/bench_pde.py``), the README scenario table — iterates
+— the :class:`~repro.pde.solver.Simulation` driver, ``examples/pde_zoo.py``,
+the README scenario table — iterates
 :func:`known_steppers` instead of hard-coding workload modules. A third-party
 stepper (a reaction-diffusion system, a wave equation, ...) becomes a named
 scenario the moment it calls :func:`register_stepper`, with zero edits

@@ -21,7 +21,7 @@ paper exercises it. This module owns the simulation loop once:
   member dim is the logical ``batch`` axis).
 
 Steppers register under a string key (:mod:`repro.pde.registry`, mirroring
-``precision/registry.py``), so benchmarks, examples and docs enumerate
+``precision/registry.py``), so tests, examples and docs enumerate
 scenarios instead of importing workload modules. See DESIGN.md §9.
 
 The driver owns THREE arithmetic planes (``run(..., execution=...)``,
@@ -175,7 +175,7 @@ class Stepper:
     multiplication sites (``sites``) — the rows a tracked run's SiteTracker
     carries. ``name`` is stamped by ``register_stepper``; ``failure_mode``
     and ``story`` are documentation metadata surfaced by the README scenario
-    table and the per-stepper benchmark suite.
+    table and ``examples/pde_zoo.py``.
     """
 
     name: str = "?"

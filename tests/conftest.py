@@ -1,42 +1,24 @@
-"""Shared test config: property-test backend selection + example budgets.
+"""Shared test config: the hypothesis profile and the ``profiled`` fixture.
 
-The bit-level property modules (test_flexformat, test_r2f2, test_alu,
-test_pack) are written against the hypothesis API. The baked runtime image
-does not ship hypothesis and the repo installs nothing, so when the real
-package is absent we install ``tests/_hypothesis_stub.py`` (same API
-surface: kwargs-``given``, ``settings``, ``floats``/``integers``
-strategies; deterministic, edge-first, bounded) as ``sys.modules
-["hypothesis"]`` before collection. Either way the per-test example count
-is capped by ``REPRO_HYPOTHESIS_EXAMPLES`` (default 50) so the CI fast
-tier's property pass stays inside its time budget; set it higher locally
-for a deeper sweep.
+The ``repro_ci`` profile sets ``deadline=None`` (a property's first example
+compiles) and a default budget of ``REPRO_HYPOTHESIS_EXAMPLES`` examples
+(50 when unset). A test's own ``@settings(max_examples=...)`` wins over the
+profile.
 """
 
 import glob
 import os
-import sys
 from typing import Any, Dict, NamedTuple
 
+import hypothesis
 import pytest
 
-collect_ignore = []
-
-try:
-    import hypothesis
-
-    _BUDGET = int(os.environ.get("REPRO_HYPOTHESIS_EXAMPLES", "50"))
-    hypothesis.settings.register_profile(
-        "repro_ci", max_examples=_BUDGET, deadline=None
-    )
-    hypothesis.settings.load_profile("repro_ci")
-except ImportError:
-    import importlib.util
-
-    _path = os.path.join(os.path.dirname(__file__), "_hypothesis_stub.py")
-    _spec = importlib.util.spec_from_file_location("hypothesis", _path)
-    _stub = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _stub
-    _spec.loader.exec_module(_stub)
+hypothesis.settings.register_profile(
+    "repro_ci",
+    max_examples=int(os.environ.get("REPRO_HYPOTHESIS_EXAMPLES", "50")),
+    deadline=None,
+)
+hypothesis.settings.load_profile("repro_ci")
 
 
 class HostSpan(NamedTuple):

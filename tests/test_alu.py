@@ -1,12 +1,12 @@
 """Bit-level suites for the flexible ALU ops (repro.alu) + paper gates.
 
-Property tests (hypothesis; the vendored stub supplies the API when the
-real package is absent — see tests/conftest.py) pin the op law against f64
-oracles: quantize-operands -> substrate op -> quantize-result at the
-effective ``E(EB+k)M(MB+FX-k)`` format, with NO tail truncation (only the
-multiplier models dropped partial products). Covered operand regimes:
-Sterbenz cancellation (exact subtraction), the subnormal floor, and the
-near-overflow edge.
+Property tests (hypothesis) pin the op law against f64 oracles:
+quantize-operands -> substrate op -> quantize-result at the effective
+``E(EB+k)M(MB+FX-k)`` format, with NO tail truncation (only the multiplier
+models dropped partial products). Bounds of ``width=32`` strategies are
+float32 values: hypothesis refuses a bound float32 cannot represent.
+Covered operand regimes: Sterbenz cancellation (exact subtraction), the
+subnormal floor, and the near-overflow edge.
 
 Paper-pattern gates mirror §5's per-workload story for the ops the SWE
 momentum flux now routes through the engine: fixed E5M10 add/divide blow up
@@ -103,7 +103,7 @@ def test_prop_div_matches_f64_oracle(a, b, k):
 
 @settings(max_examples=120, deadline=None)
 @given(
-    x=st.floats(min_value=1e-6, max_value=1e6, allow_nan=False, allow_infinity=False, width=32),
+    x=st.floats(min_value=float(np.float32(1e-6)), max_value=1e6, allow_nan=False, allow_infinity=False, width=32),
     k=st.integers(0, 3),
 )
 def test_prop_rsqrt_matches_f64_oracle(x, k):
@@ -117,7 +117,7 @@ def test_prop_rsqrt_matches_f64_oracle(x, k):
 
 @settings(max_examples=120, deadline=None)
 @given(
-    a=st.floats(min_value=0.001, max_value=1000.0, allow_nan=False, allow_infinity=False, width=32),
+    a=st.floats(min_value=float(np.float32(1e-3)), max_value=1000.0, allow_nan=False, allow_infinity=False, width=32),
     r=st.floats(min_value=0.5, max_value=2.0, allow_nan=False, allow_infinity=False, width=32),
     k=st.integers(0, 3),
 )

@@ -263,7 +263,7 @@ class TestMegaDispatch:
 
 def _count_pallas_weighted(jaxpr) -> int:
     """Scan-weighted pallas_call count — kernel LAUNCHES at runtime, not
-    call sites in the jaxpr text (mirrors benchmarks.bench_pde)."""
+    call sites in the jaxpr text."""
     n = 0
     for eqn in jaxpr.eqns:
         w = eqn.params.get("length", 1) if eqn.primitive.name == "scan" else 1

@@ -42,17 +42,29 @@ class TestSelectK:
 
 
 class TestTileMultiply:
-    def test_more_accurate_than_fixed_half(self):
+    # the paper's three widths (Fig. 6): k-bit R2F2 against the fixed format
+    # of the same width; it reports 70.2 / 70.6 / 70.7% lower mean error
+    @pytest.mark.parametrize(
+        "fmt, fixed",
+        [
+            (FlexFormat(3, 9, 3), (5, 10)),
+            (FlexFormat(3, 8, 3), (5, 9)),
+            (FlexFormat(3, 7, 3), (5, 8)),
+        ],
+        ids=["r2f2_16-E5M10", "r2f2_15-E5M9", "r2f2_14-E5M8"],
+    )
+    def test_more_accurate_than_fixed_half(self, fmt, fixed):
+        e, m = fixed
         rng = np.random.default_rng(0)
         a = (10.0 ** rng.uniform(-4, 4, 50000)).astype(np.float32)
         b = (10.0 ** rng.uniform(-4, 4, 50000)).astype(np.float32)
         exact = a.astype(np.float64) * b.astype(np.float64)
-        p_rr, _ = r2f2_multiply(a, b, FMT, tile_shape=(100,))
+        p_rr, _ = r2f2_multiply(a, b, fmt, tile_shape=(100,))
         p_fx = np.asarray(
             quantize_em(
-                np.asarray(quantize_em(a, 5, 10)) * np.asarray(quantize_em(b, 5, 10)),
-                5,
-                10,
+                np.asarray(quantize_em(a, e, m)) * np.asarray(quantize_em(b, e, m)),
+                e,
+                m,
             ),
             np.float64,
         )

@@ -10,10 +10,11 @@ split ``k`` + §5.3 adjustment counters for ``rr_tracked``. Around that:
 eviction→resume bit-exactness through ``repro.ckpt``, admission control and
 backpressure, bucketing rules, the unified policy-artifact resolution,
 streaming, metrics, and the solver's new ``tracker0_batch`` repacking entry.
+The packing matrix and the resident batch are in ``test_service_packing.py``
+and ``test_service_resident.py``, so that a worker per file spreads them.
 """
 
 import dataclasses
-import functools
 import os
 
 import jax
@@ -21,20 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.obs as obs
 from repro.core.flexformat import FlexFormat
 from repro.core.policy import PRESETS, PrecisionConfig
-from repro.pde import (
-    AdvectionConfig,
-    BurgersConfig,
-    HeatConfig,
-    Heat2DConfig,
-    SWEConfig,
-    Simulation,
-)
+from repro.pde import BurgersConfig, HeatConfig, Simulation
 from repro.profile import PrecisionPolicy
 from repro.service import (
-    BucketKey,
     ServiceConfig,
     ServiceOverloaded,
     SimRequest,
@@ -42,156 +34,16 @@ from repro.service import (
     resolve_request,
 )
 
-TRACKED = dataclasses.replace(PRESETS["r2f2_16"], mode="rr_tracked")
-
-#: small grids: the parity matrix runs 5 steppers x 6 modes in the fast tier
-SMALL_CFGS = {
-    "heat1d": HeatConfig(nx=48),
-    "heat2d": Heat2DConfig(nx=16, ny=16),
-    "advection1d": AdvectionConfig(nx=64),
-    "burgers1d": BurgersConfig(nx=48),
-    "swe2d": SWEConfig(nx=16, ny=16),
-}
-
-#: (label, config, bit_exact) — rr_tracked's guarantee is final split k +
-#: §5.3 counters (bit-exactness additionally holds on the reference plane
-#: and is asserted there)
-MODES = (
-    ("f32", PRESETS["f32"], True),
-    ("bf16", PRESETS["bf16"], True),
-    ("e5m10", PRESETS["e5m10"], True),
-    ("r2f2_16", PRESETS["r2f2_16"], True),
-    ("deploy", PRESETS["deploy"], True),
-    ("rr_tracked", TRACKED, True),
+from _service_cases import (
+    MODES,
+    SMALL_CFGS,
+    TRACKED,
+    _assert_trackers_equal,
+    _assert_tree_equal,
+    _fail_second_chunk,
+    _scaled,
+    _solo,
 )
-
-
-def _scaled(state, s):
-    return jax.tree_util.tree_map(lambda x: (s * x).astype(x.dtype), state)
-
-
-@functools.lru_cache(maxsize=None)
-def _solo(stepper, mode, steps, every, scale=None):
-    """The solo reference run of a request (shared by the tests that serve it)."""
-    cfg = SMALL_CFGS[stepper]
-    sim = Simulation(stepper, cfg, dict((m[0], m[1]) for m in MODES)[mode])
-    state0 = None if scale is None else _scaled(sim.stepper.init_state(cfg), scale)
-    return sim.run(steps, snapshot_every=every, state0=state0)
-
-
-def _assert_trackers_equal(a, b):
-    assert (a is None) == (b is None)
-    if a is None:
-        return
-    np.testing.assert_array_equal(np.asarray(a.state.k), np.asarray(b.state.k))
-    np.testing.assert_array_equal(
-        np.asarray(a.state.overflow_steps), np.asarray(b.state.overflow_steps)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(a.state.shrink_steps), np.asarray(b.state.shrink_steps)
-    )
-
-
-# ---------------------------------------------------------------------------
-# the acceptance matrix: packing invisibility per stepper x mode
-# ---------------------------------------------------------------------------
-
-
-class TestPackingInvisibility:
-    @pytest.mark.parametrize("stepper", sorted(SMALL_CFGS))
-    @pytest.mark.parametrize("mode", [m[0] for m in MODES])
-    def test_bucketed_equals_solo(self, stepper, mode):
-        """Two requests share a bucket; the second joins mid-flight with a
-        misaligned cadence (forcing chunk subdivision); both must reproduce
-        their solo runs."""
-        prec = dict((m[0], m[1]) for m in MODES)[mode]
-        bit_exact = dict((m[0], m[2]) for m in MODES)[mode]
-        cfg = SMALL_CFGS[stepper]
-        sim = Simulation(stepper, cfg, prec)
-        s0b = _scaled(sim.stepper.init_state(cfg), 0.5)
-
-        svc = SimService(ServiceConfig())
-        hA = svc.submit(
-            SimRequest(stepper, steps=24, precision=prec, cfg=cfg,
-                       snapshot_every=8, execution="reference")
-        )
-        assert svc.pump()  # A runs its first chunk alone...
-        hB = svc.submit(  # ...then B joins the running bucket mid-flight
-            SimRequest(stepper, steps=18, precision=prec, cfg=cfg,
-                       snapshot_every=6, state0=s0b, execution="reference")
-        )
-        svc.run_until_idle()
-        assert hA.status == "done" and hB.status == "done"
-        # they really shared one bucket (continuous batching, not siblings)
-        assert svc.metrics.occupancy()[1] == 2
-
-        soloA = _solo(stepper, mode, 24, 8)
-        soloB = _solo(stepper, mode, 18, 6, 0.5)
-        for h, solo in ((hA, soloA), (hB, soloB)):
-            if bit_exact:
-                np.testing.assert_array_equal(
-                    np.stack(h.snapshots), np.asarray(solo.snapshots)
-                )
-                np.testing.assert_array_equal(
-                    np.asarray(h.result().state), np.asarray(solo.state)
-                )
-            _assert_trackers_equal(h.result().tracker, solo.tracker)
-
-    def test_fused_bucket_parity(self):
-        """The fused plane: deploy rides bf16 kernels bit-exactly through a
-        shared bucket with a mid-flight joiner; rr_tracked converges to the
-        identical final split + §5.3 counters."""
-        cfg = Heat2DConfig(nx=16, ny=16)
-        for prec, bit_exact in ((PRESETS["deploy"], True), (TRACKED, False)):
-            sim = Simulation("heat2d", cfg, prec)
-            if not sim.fused_eligible():
-                pytest.skip("heat2d not fused-eligible in this build")
-            svc = SimService(ServiceConfig())
-            hA = svc.submit(
-                SimRequest("heat2d", steps=12, precision=prec, cfg=cfg,
-                           snapshot_every=4, execution="fused")
-            )
-            assert svc.pump()
-            hB = svc.submit(
-                SimRequest("heat2d", steps=12, precision=prec, cfg=cfg,
-                           snapshot_every=4,
-                           state0=_scaled(sim.stepper.init_state(cfg), 0.5),
-                           execution="fused")
-            )
-            svc.run_until_idle()
-            assert svc.metrics.occupancy()[1] == 2
-            soloA = sim.run(12, snapshot_every=4, execution="fused")
-            soloB = sim.run(
-                12, snapshot_every=4, execution="fused",
-                state0=_scaled(sim.stepper.init_state(cfg), 0.5),
-            )
-            for h, solo in ((hA, soloA), (hB, soloB)):
-                _assert_trackers_equal(h.result().tracker, solo.tracker)
-                if bit_exact:
-                    np.testing.assert_array_equal(
-                        np.stack(h.snapshots), np.asarray(solo.snapshots)
-                    )
-                else:
-                    np.testing.assert_allclose(
-                        np.stack(h.snapshots), np.asarray(solo.snapshots),
-                        rtol=2e-2, atol=1e-5,
-                    )
-
-    def test_remainder_horizon(self):
-        """A horizon that is not a multiple of the cadence drains with the
-        same snapshots + final state as solo (remainder steps run, no
-        trailing snapshot)."""
-        cfg = HeatConfig(nx=48)
-        svc = SimService(ServiceConfig())
-        h = svc.submit(
-            SimRequest("heat1d", steps=23, precision="r2f2_16", cfg=cfg,
-                       snapshot_every=8, execution="reference")
-        )
-        svc.run_until_idle()
-        solo = Simulation("heat1d", cfg, PRESETS["r2f2_16"]).run(23, snapshot_every=8)
-        assert h.snapshot_steps == [8, 16]
-        np.testing.assert_array_equal(np.stack(h.snapshots), np.asarray(solo.snapshots))
-        np.testing.assert_array_equal(np.asarray(h.result().state), np.asarray(solo.state))
 
 
 # ---------------------------------------------------------------------------
@@ -369,173 +221,6 @@ class TestEvictionResume:
         np.testing.assert_array_equal(
             np.stack(hLong.snapshots), np.asarray(soloL.snapshots)
         )
-
-
-# ---------------------------------------------------------------------------
-# the resident batch: restacked only on a membership change
-# ---------------------------------------------------------------------------
-
-
-def _fail_second_chunk(svc, key):
-    """Make the chunk program of bucket key ``key`` raise on its second call."""
-    get, calls = svc._compiler.get, []
-
-    def wrapped(sim, k, *args, **kw):
-        fn, fresh = get(sim, k, *args, **kw)
-        if k != key:
-            return fn, fresh
-
-        def chunk(*xs):
-            calls.append(k)
-            if len(calls) == 2:
-                raise RuntimeError("injected chunk failure")
-            return fn(*xs)
-
-        return chunk, fresh
-
-    svc._compiler.get = wrapped
-
-
-def _assert_tree_equal(a, b):
-    jax.tree_util.tree_map(
-        lambda x, y: np.testing.assert_array_equal(np.asarray(x), np.asarray(y)), a, b
-    )
-
-
-class TestResidentBatch:
-    @pytest.mark.parametrize("stepper", sorted(SMALL_CFGS))
-    @pytest.mark.parametrize("mode", [m[0] for m in MODES])
-    def test_schedule_bit_identical_to_solo(self, stepper, mode, tmp_path):
-        """Members joining and draining mid-run, one evicted and resumed, and
-        a sibling bucket whose second chunk raises: every member that
-        finishes matches its solo run, and the failed one leaves with its
-        state as of its last good chunk."""
-        prec = dict((m[0], m[1]) for m in MODES)[mode]
-        other = "bf16" if mode == "f32" else "f32"
-        cfg = SMALL_CFGS[stepper]
-        init = Simulation(stepper, cfg, prec).stepper.init_state(cfg)
-
-        def req(steps, every, scale=None, precision=prec):
-            return SimRequest(stepper, steps=steps, precision=precision, cfg=cfg,
-                              snapshot_every=every,
-                              state0=None if scale is None else _scaled(init, scale),
-                              execution="reference")
-
-        svc = SimService(ServiceConfig(ckpt_dir=str(tmp_path), auto_resume=False))
-        hA = svc.submit(req(24, 8))
-        hB = svc.submit(req(18, 6, 0.5))
-        svc.pump()  # A and B at 6
-        hC = svc.submit(req(12, 4, 1.5))
-        svc.pump()  # C joins: all advance 2
-        svc.evict(hB.id)  # B leaves mid-run at 8 (not one of its events)
-        hD = svc.submit(req(12, 4, precision=PRESETS[other]))  # a sibling bucket
-        _fail_second_chunk(svc, hD._record.key)
-        svc.pump()
-        svc.pump()
-        svc.resume(hB.id)
-        failures = 0
-        for _ in range(100):
-            try:
-                if not svc.pump():
-                    break
-            except RuntimeError:
-                failures += 1
-        assert failures == 1
-
-        for h, run in ((hA, (24, 8)), (hB, (18, 6, 0.5)), (hC, (12, 4, 1.5))):
-            assert h.status == "done"
-            solo = _solo(stepper, mode, *run)
-            np.testing.assert_array_equal(np.stack(h.snapshots), np.asarray(solo.snapshots))
-            _assert_tree_equal(h.result().state, solo.state)
-            _assert_trackers_equal(h.result().tracker, solo.tracker)
-        assert svc.metrics.evicted == 1 and svc.metrics.resumed == 1
-
-        recD = hD._record
-        assert hD.status == "failed" and recD.elapsed == 4
-        _assert_tree_equal(recD.state, _solo(stepper, other, 4, 4).state)
-        assert recD.resident_in is None
-        assert svc.active_members == 0
-
-    @pytest.mark.parametrize("mode", [m[0] for m in MODES])
-    def test_lone_request_restacks_once(self, mode):
-        """A request alone in its bucket for 8 chunks builds its batch once
-        and reuses it for the other 7."""
-        prec = dict((m[0], m[1]) for m in MODES)[mode]
-        svc = SimService(ServiceConfig())
-        h = svc.submit(SimRequest("heat1d", steps=16, precision=prec,
-                                  cfg=HeatConfig(nx=48), snapshot_every=2))
-        svc.run_until_idle()
-        assert h.status == "done" and h.result().chunks == 8
-        m = svc.metrics
-        assert (m.restacks, m.resident_chunks) == (1, 7)
-        assert m.registry.counter("repro_service_restacks_total").total() == 1
-        assert m.registry.counter("repro_service_resident_chunks_total").total() == 7
-
-    @pytest.mark.parametrize("stepper", sorted(SMALL_CFGS))
-    def test_tracker_telemetry_matches_chunk_outputs(self, stepper, monkeypatch):
-        """With telemetry on, each request's per-chunk tracker series is its
-        row of every chunk's stacked output tracker, entry for entry."""
-        cfg = SMALL_CFGS[stepper]
-        s0b = _scaled(Simulation(stepper, cfg, TRACKED).stepper.init_state(cfg), 0.5)
-        svc = SimService(ServiceConfig())
-        rows = {}  # request id -> [(step, k, grew, shrank)] sliced from the outputs
-        calls = {}  # id of a call's outputs -> (outputs, members, chunk steps)
-        get = svc._compiler.get
-
-        def spy(sim, key, chunk, *args, **kw):
-            fn, fresh = get(sim, key, chunk, *args, **kw)
-
-            def run(*xs):
-                out = fn(*xs)
-                (bucket,) = svc._live_buckets()
-                calls[id(out)] = (out, list(bucket.members), chunk)
-                return out
-
-            return run, fresh
-
-        sync = jax.block_until_ready
-
-        def synced(x):
-            # a chunk's rows as the bucket syncs it: a chunk run ahead and
-            # dropped at a join is never synced, so it is no member's chunk
-            if id(x) in calls:
-                out, members, chunk = calls.pop(id(x))
-                st = out[2].state
-                for i, m in enumerate(members):
-                    rows.setdefault(m.id, []).append((
-                        m.elapsed + chunk, np.asarray(st.k[i]),
-                        np.asarray(st.overflow_steps[i]), np.asarray(st.shrink_steps[i]),
-                    ))
-            return sync(x)
-
-        svc._compiler.get = spy
-        monkeypatch.setattr(jax, "block_until_ready", synced)
-        obs.enable()
-        try:
-            hA = svc.submit(SimRequest(stepper, steps=24, precision=TRACKED, cfg=cfg,
-                                       snapshot_every=8, execution="reference"))
-            svc.pump()
-            hB = svc.submit(SimRequest(stepper, steps=18, precision=TRACKED, cfg=cfg,
-                                       snapshot_every=6, state0=s0b,
-                                       execution="reference"))
-            svc.run_until_idle()
-            tel = obs.active().telemetry
-            names = hA.result().tracker.names
-            series = {
-                h.id: [tel.series(f"req{h.id}:{stepper}", n) for n in names]
-                for h in (hA, hB)
-            }
-        finally:
-            obs.disable()
-
-        for h in (hA, hB):
-            expected = rows[h.id]
-            assert len(expected) == h.result().chunks
-            for i, s in enumerate(series[h.id]):
-                assert s.steps == [step for step, *_ in expected]
-                assert s.k == [int(k[i]) for _, k, _, _ in expected]
-                assert s.grew == [int(g[i]) for _, _, g, _ in expected]
-                assert s.shrank == [int(r[i]) for _, _, _, r in expected]
 
 
 def _assert_bits_equal(a, b):

@@ -21,6 +21,7 @@ from repro.pde import (
     initial_wave,
     known_steppers,
     register_stepper,
+    scenarios,
     simulate_heat,
     simulate_swe,
     SWEConfig,
@@ -47,6 +48,15 @@ class TestStepperRegistry:
             assert st.name == name
             assert st.sites, name  # every workload declares its sites
             assert st.failure_mode in ("underflow", "overflow", "nonlinear-drift")
+
+    def test_every_stepper_has_a_scenario(self):
+        """The paper-pattern table (chip_smoke.py, examples/pde_zoo.py) judges
+        every registered stepper at its own configuration type."""
+        table = scenarios()
+        assert sorted(table) == list(known_steppers())
+        for name, sc in table.items():
+            assert type(sc.cfg) is type(get_stepper(name).default_config()), name
+            assert sc.judge in ("rel", "corr"), name
 
     def test_unknown_stepper_raises(self):
         with pytest.raises(KeyError, match="no PDE stepper"):
